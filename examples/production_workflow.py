@@ -23,18 +23,16 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))   # run from anywhere, uninstalled
 
 import numpy as np
-import jax
+
+import quest_tpu as qt
+from quest_tpu import compile_cache
+from quest_tpu.circuits import Circuit
 
 # 1. persistent compilation cache --------------------------------------------
 # every compile slower than a second is saved to disk; identical programs
-# (same circuit, shapes, mesh) load in milliseconds on any later run
-cache = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), ".jax_cache")
-jax.config.update("jax_compilation_cache_dir", cache)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
-import quest_tpu as qt
-from quest_tpu.circuits import Circuit
+# (same circuit, shapes, mesh) load in milliseconds on any later run.
+# JAX_COMPILATION_CACHE_DIR places it; otherwise it is <repo>/.jax_cache
+compile_cache.enable()
 
 env = qt.createQuESTEnv(num_devices=1, seed=[11])
 n = 16

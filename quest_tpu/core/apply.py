@@ -21,19 +21,36 @@ convention) is:
 
 1. reshape to split out the target (and control) axes — rank ``2(k+c)+1``,
    never rank ``N``, so XLA sees small static shapes;
-2. transpose those axes to the front (one fused copy);
-3. a ``(2^k, 2^k) @ (2^k, 2^(N-k))`` matmul — MXU-shaped for big ``k``;
-4. inverse transpose and flatten.
+2. mix the target axes in place: up to 4 targets as unrolled complex
+   multiply-adds over the ``2^k`` target slices, more as a contraction;
+   nothing is transposed;
+3. pass the slices outside the controlled subspace through untouched
+   (the reference's ctrlMask skip, ``QuEST_cpu.c:2146-2210``, without
+   per-amplitude branching), and flatten.
 
-Controls are *sliced*, not masked: the control axes are indexed at their
-required bit, so only the controlled subspace is touched — the same work
-saving as the reference's ctrlMask skip (``QuEST_cpu.c:2146-2210``) without
-any per-amplitude branching.
+Targets that form a contiguous block skip the split: one batched matmul
+on the ``(pre, 2^k, post)`` view.
 
 Diagonal operators (phase gates, multiRotateZ, dephasing) never pair
 amplitudes; they are broadcast elementwise multiplies (`apply_diagonal`),
 which XLA fuses into a single memory pass — the analogue of
 ``statevec_phaseShiftByTerm`` (``QuEST_cpu.c:2946-2985``).
+
+Lane qubits
+-----------
+The TPU tiles the two minor dimensions of every array by ``(8, 128)``. A
+split at qubit ``p`` leaves a minor dimension of ``2^p``, so a gate, control
+or diagonal factor on a qubit below 7 would give XLA arrays whose minor
+dimension is 1..64: padded up to 128x in device memory, and compiled to
+code whose size and compile time grow with the state (a single Hadamard on
+qubit 0 of 20 qubits compiled for 95 s into 63 MB of code and 537 MB of
+temporaries for an 8 MB state). So whenever such a qubit is involved and
+the register is large enough, the state is viewed as ``(rows, 128)``
+instead: the low 7 qubits ("lane qubits") are the 128-wide minor axis and
+never split. Lane targets fold into one ``(2^j*128)``-square operator
+over the lanes and the ``j`` row targets (a matmul on the MXU); lane and
+row controls become ``iota`` masks; lane factors of a diagonal become a
+128-wide factor row.
 """
 
 from __future__ import annotations
@@ -45,6 +62,9 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
+    "LANE_BITS",
+    "pass_boundary",
+    "transpose_qubits",
     "apply_unitary",
     "apply_diagonal",
     "bitmask",
@@ -52,6 +72,24 @@ __all__ = [
     "permutation_to_sorted_desc",
     "split_shape",
 ]
+
+
+LANE_BITS = 7            # 2^7 = 128: the TPU's lane width
+_LANES = 1 << LANE_BITS
+
+
+def pass_boundary(state: jnp.ndarray) -> jnp.ndarray:
+    """Close one operator's memory pass in a traced chain of operators.
+
+    XLA turns a contraction over a 2-wide axis into elementwise
+    arithmetic and then fuses a chain of them into one expression in
+    which every output recomputes its ``2^k`` inputs: on a described v5e
+    chip, eight one-qubit gates on the high qubits of a 28-qubit state
+    compiled in 56 s to 233 MB of code, and a whole 28-qubit circuit did
+    not finish compiling in ten minutes. The barrier keeps each operator
+    one pass over the state (8 gates: 13 s, 23 MB of code) — the memory
+    model the planner prices anyway."""
+    return jax.lax.optimization_barrier(state)
 
 
 def bitmask(qubits: Sequence[int]) -> int:
@@ -154,11 +192,13 @@ def apply_unitary(
     with jax.named_scope(
             f"gate_u{k}q_t{'_'.join(map(str, targets))}"
             + (f"_c{len(controls)}" if controls else "")):
+        if _use_lanes(num_qubits, targets):
+            return _apply_unitary_lanes(state, num_qubits, u, targets,
+                                        ctrl_mask, flip_mask, prec)
         # --- no-transpose fast paths (uncontrolled, contiguous ends) ------
         # A gate on the lowest k qubits is a plain right-matmul on the
         # (rest, 2^k) view; on the highest k, a left-matmul on (2^k, rest).
-        # Either costs exactly one read+write pass — the generic path below
-        # pays materialised transposes around the matmul.
+        # Either costs exactly one read+write pass with one matmul.
         if not controls and set(targets) == set(range(k)):
             u = jnp.asarray(u, dtype=state.dtype)
             if targets != tuple(range(k)):
@@ -181,33 +221,194 @@ def apply_unitary(
             out = jnp.matmul(u, s, precision=prec)
             return out.reshape(-1)
 
-        pos_desc = tuple(sorted(targets + controls, reverse=True))
-        shape = split_shape(num_qubits, pos_desc)
-        axis_of = {p: 2 * i + 1 for i, p in enumerate(pos_desc)}
+        return _apply_unitary_split(state, num_qubits, u, targets,
+                                    ctrl_mask, flip_mask, prec)
 
-        ctrl_axes = [axis_of[c] for c in controls]
-        targ_axes = [axis_of[t] for t in sorted(targets, reverse=True)]
-        moved = set(ctrl_axes) | set(targ_axes)
-        rest_axes = [ax for ax in range(len(shape)) if ax not in moved]
-        perm = ctrl_axes + targ_axes + rest_axes
 
+# above this many targets the unrolled form's 4^k terms cost more than a
+# contraction
+_UNROLL_TARGETS = 4
+
+
+def _apply_unitary_split(state, num_qubits: int, u, targets: tuple,
+                         ctrl_mask: int, flip_mask: int, prec):
+    """The general path of :func:`apply_unitary`: split the flat axis at
+    every target and mix the target axes in place — up to
+    ``_UNROLL_TARGETS`` targets as unrolled complex multiply-adds over the
+    ``2^k`` target slices, more as a contraction — then select the
+    controlled subspace with an ``iota`` mask, one pass later. Nothing is
+    transposed and controls never split the state: a transpose of a split
+    2^28-amplitude state compiled for minutes into hundreds of MB of TPU
+    code, splitting at 19 controls took 319 s, and a select fused into
+    the gate compiled a CNOT for ten minutes; this form compiles each in
+    seconds."""
+    k = len(targets)
+    pos_desc = tuple(sorted(targets, reverse=True))
+    shape = split_shape(num_qubits, pos_desc)
+    axis_of = {p: 2 * i + 1 for i, p in enumerate(pos_desc)}
+    t_axes = [axis_of[t] for t in targets]    # gate index bit j <-> t_j
+    x = state.reshape(shape)
+    if k > _UNROLL_TARGETS:
+        # u as a (2,)*2k tensor: output bits k-1..0, then input bits
+        in_axes = [t_axes[j] for j in reversed(range(k))]
+        ut = jnp.asarray(u, dtype=state.dtype).reshape((2,) * (2 * k))
+        new = jnp.tensordot(ut, x, axes=(list(range(k, 2 * k)), in_axes),
+                            precision=prec)
+        new = jnp.moveaxis(new, list(range(k)), in_axes)
+    else:
+        new = _mix_unrolled(x, u, t_axes, state.dtype)
+    new = new.reshape(-1)
+    if not ctrl_mask:
+        return new
+    return _select_controls(pass_boundary(new), state, num_qubits,
+                            ctrl_mask, flip_mask)
+
+
+def _mix_unrolled(x, u, t_axes: list, dtype):
+    """Mix the target slices of the split state ``x``: output slice ``r``
+    is ``sum_m u[r, m] * slice_m``. A host matrix drops its zero terms and
+    keeps its unit ones as plain slices (X, CNOT and SWAP move data
+    without arithmetic)."""
+    k = len(t_axes)
+    host = u if isinstance(u, np.ndarray) else None
+    uj = jnp.asarray(u, dtype=dtype)
+    slices = []
+    for m in range(1 << k):
+        idx = [slice(None)] * x.ndim
+        for j, ax in enumerate(t_axes):
+            b = (m >> j) & 1
+            idx[ax] = slice(b, b + 1)
+        slices.append(x[tuple(idx)])
+
+    def term(r, m):
+        if host is not None and host[r, m] == 1:
+            return slices[m]
+        return uj[r, m] * slices[m]
+    outs = [sum(term(r, m) for m in range(1 << k)
+                if host is None or host[r, m] != 0)
+            for r in range(1 << k)]
+
+    def assemble(j, base):
+        if j == k:
+            return outs[base]
+        return jnp.concatenate([assemble(j + 1, base),
+                                assemble(j + 1, base | (1 << j))],
+                               axis=t_axes[j])
+    return assemble(0, 0)
+
+
+_SWAP = np.eye(4, dtype=np.complex128)[[0, 2, 1, 3]]
+
+
+def transpose_qubits(state: jnp.ndarray, num_qubits: int,
+                     axes: Sequence[int]) -> jnp.ndarray:
+    """``state.reshape((2,)*n).transpose(axes).reshape(-1)`` as a sequence
+    of qubit swaps, one pass each. A rank-n transpose leaves the two
+    minor dimensions of size 2: padded 64x on a TPU and compiled into code
+    that grows with the state."""
+    n = num_qubits
+    # the bit at output position p comes from input position src[p]
+    src = [n - 1 - int(axes[n - 1 - p]) for p in range(n)]
+    cur = list(range(n))          # cur[p]: input position now held at p
+    for p in range(n):
+        if cur[p] == src[p]:
+            continue
+        q = cur.index(src[p])
+        state = pass_boundary(apply_unitary(state, n, _SWAP, (p, q)))
+        cur[p], cur[q] = cur[q], cur[p]
+    return state
+
+
+def _use_lanes(num_qubits: int, targets: tuple) -> bool:
+    """Whether :func:`apply_unitary` takes the lane view: a target below
+    qubit 7, and the lane operator (``(2^j*128)^2`` entries for ``j`` row
+    targets) smaller than the state — below that size the split view's
+    padding costs less than the operator."""
+    if num_qubits < LANE_BITS or min(targets) >= LANE_BITS:
+        return False
+    rows = sum(t >= LANE_BITS for t in targets)
+    return 2 * (LANE_BITS + rows) < num_qubits
+
+
+def _support_operator(u, targets: tuple, support: tuple, ctrl_mask: int,
+                      flip_mask: int, dtype):
+    """``u`` embedded over the qubits ``support`` (ascending: bit ``i`` of
+    the operator's index is qubit ``support[i]``), conditioned on the
+    controls of ``ctrl_mask`` that lie in the support and the identity
+    elsewhere. Host arithmetic for a host matrix; a gather for a traced
+    one (parameterised gates)."""
+    dim = 1 << len(support)
+    idx = np.arange(dim)
+    pos = {q: i for i, q in enumerate(support)}
+    m = np.zeros(dim, dtype=np.int64)
+    tmask = 0
+    for j, t in enumerate(targets):
+        m |= ((idx >> pos[t]) & 1) << j
+        tmask |= 1 << pos[t]
+    cm = cw = 0
+    for q in support:
+        if (ctrl_mask >> q) & 1:
+            cm |= 1 << pos[q]
+            if not (flip_mask >> q) & 1:
+                cw |= 1 << pos[q]
+    ok = (idx & cm) == cw
+    base = idx & ~tmask
+    sel = (base[:, None] == base[None, :]) & ok[None, :]
+    ident = np.diag(~ok)
+    if isinstance(u, np.ndarray):
+        return np.where(sel, u[m[:, None], m[None, :]], ident).astype(dtype)
+    u = jnp.asarray(u, dtype=dtype)
+    return jnp.where(sel, u[m[:, None], m[None, :]], ident.astype(dtype))
+
+
+def _select_controls(new, old, num_qubits: int, ctrl_mask: int,
+                     flip_mask: int):
+    """``new`` where every control of ``ctrl_mask`` holds, else ``old`` —
+    masks from ``iota`` over the ``(rows, 128)`` view (the flat index for
+    a register narrower than one row)."""
+    if not ctrl_mask:
+        return new
+    want = ctrl_mask & ~flip_mask
+    if num_qubits < LANE_BITS:
+        i = jax.lax.broadcasted_iota(jnp.int32, (1 << num_qubits,), 0)
+        return jnp.where((i & ctrl_mask) == want, new, old)
+    rows = 1 << (num_qubits - LANE_BITS)
+    cond = None
+    for mask, w, shape, axis in (
+            (ctrl_mask & (_LANES - 1), want & (_LANES - 1), (1, _LANES), 1),
+            (ctrl_mask >> LANE_BITS, want >> LANE_BITS, (rows, 1), 0)):
+        if mask:
+            i = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+            c = (i & mask) == w
+            cond = c if cond is None else cond & c
+    return jnp.where(cond, new.reshape(rows, _LANES),
+                     old.reshape(rows, _LANES)).reshape(-1)
+
+
+def _apply_unitary_lanes(state, num_qubits: int, u, targets: tuple,
+                         ctrl_mask: int, flip_mask: int, prec):
+    """:func:`apply_unitary` for operators touching a lane qubit (module
+    docstring, "Lane qubits")."""
+    low = _LANES - 1
+    row_t = tuple(sorted(t for t in targets if t >= LANE_BITS))
+    m = _support_operator(u, targets, tuple(range(LANE_BITS)) + row_t,
+                          ctrl_mask & low, flip_mask, state.dtype)
+    rows = 1 << (num_qubits - LANE_BITS)
+    if not row_t:
+        new = jnp.matmul(state.reshape(rows, _LANES), m.T, precision=prec)
+    else:
+        # row targets move next to the lane axis: the contraction runs
+        # over (row targets, lanes), lowest row target just above lane 6
+        rdesc = tuple(t - LANE_BITS for t in reversed(row_t))
+        shape = split_shape(num_qubits - LANE_BITS, rdesc) + (_LANES,)
+        targ_axes = [2 * i + 1 for i in range(len(rdesc))]
+        rest = [a for a in range(len(shape) - 1) if a not in targ_axes]
+        perm = rest + targ_axes + [len(shape) - 1]
         arr = state.reshape(shape).transpose(perm)
-        ctrl_idx = tuple(0 if (flip_mask >> c) & 1 else 1 for c in controls)
-
-        sub = arr[ctrl_idx] if controls else arr
-        rest_shape = sub.shape[k:]
-
-        u = jnp.asarray(u, dtype=state.dtype)
-        row_perm = permutation_to_sorted_desc(targets)
-        if not np.array_equal(row_perm, np.arange(1 << k)):
-            u = u[row_perm][:, row_perm]
-
-        new = jnp.matmul(u, sub.reshape(1 << k, -1), precision=prec)
-        new = new.reshape((2,) * k + rest_shape)
-        arr = arr.at[ctrl_idx].set(new) if controls else new
-
-        inv = np.argsort(perm)
-        return arr.transpose(inv).reshape(-1)
+        new = jnp.matmul(arr.reshape(-1, m.shape[0]), m.T, precision=prec)
+        new = new.reshape(arr.shape).transpose(np.argsort(perm))
+    return _select_controls(new.reshape(-1), state, num_qubits,
+                            ctrl_mask & ~low, flip_mask)
 
 
 def apply_diagonal(
@@ -225,9 +426,36 @@ def apply_diagonal(
     """
     pos_desc = tuple(sorted((int(q) for q in qubits), reverse=True))
     with jax.named_scope(f"gate_diag_q{'_'.join(map(str, pos_desc))}"):
+        if num_qubits >= LANE_BITS and pos_desc \
+                and pos_desc[-1] < LANE_BITS:
+            return _apply_diagonal_lanes(state, num_qubits, pos_desc,
+                                         diag_tensor)
         shape = split_shape(num_qubits, pos_desc)
         bshape = [1] * len(shape)
         for i in range(len(pos_desc)):
             bshape[2 * i + 1] = 2
         factor = jnp.asarray(diag_tensor, dtype=state.dtype).reshape(bshape)
         return (state.reshape(shape) * factor).reshape(-1)
+
+
+def _apply_diagonal_lanes(state, num_qubits: int, pos_desc: tuple,
+                          diag_tensor):
+    """:func:`apply_diagonal` with a lane qubit among ``pos_desc``: the
+    lane factors expand to a 128-wide row of the factor table, which
+    broadcasts over the ``(..., 2, ..., 128)`` split of the row qubits."""
+    row = tuple(p - LANE_BITS for p in pos_desc if p >= LANE_BITS)
+    lane = tuple(p for p in pos_desc if p < LANE_BITS)
+    lanes = np.arange(_LANES)
+    idx = np.zeros(_LANES, dtype=np.int64)
+    for i, q in enumerate(lane):
+        idx |= ((lanes >> q) & 1) << (len(lane) - 1 - i)
+    xp = np if isinstance(diag_tensor, np.ndarray) else jnp
+    table = xp.asarray(diag_tensor).reshape(1 << len(row), 1 << len(lane))
+    table = table[:, idx]
+    shape = split_shape(num_qubits - LANE_BITS, row) + (_LANES,)
+    bshape = [1] * len(shape)
+    for i in range(len(row)):
+        bshape[2 * i + 1] = 2
+    bshape[-1] = _LANES
+    factor = jnp.asarray(table, dtype=state.dtype).reshape(bshape)
+    return (state.reshape(shape) * factor).reshape(-1)

@@ -8,6 +8,11 @@ at executable boundaries, while complex arithmetic *inside* a compiled
 program lowers fine. Every kernel therefore unpacks floats -> complex at
 trace time, computes, and packs back; XLA fuses the (de)interleaving into
 the surrounding ops for free.
+
+:func:`pack` selects between the two planes instead of stacking them: the
+TPU compiler aborts (``Check failed: IsFusibleUnalignedDUS``) on a stack
+whose operands were computed on the ``(rows, 128)`` lane view of the
+state (``core/apply.py``), while the select fuses into one pass.
 """
 
 from __future__ import annotations
@@ -26,7 +31,8 @@ def unpack(state_f: jnp.ndarray) -> jnp.ndarray:
 
 def pack(z: jnp.ndarray) -> jnp.ndarray:
     """complex array -> (2, ...) float planes (jit-internal only)."""
-    return jnp.stack([jnp.real(z), jnp.imag(z)])
+    first = jax.lax.broadcasted_iota(jnp.int32, (2,) + (1,) * z.ndim, 0) == 0
+    return jnp.where(first, jnp.real(z)[None], jnp.imag(z)[None])
 
 
 def pack_host(z: np.ndarray, real_dtype) -> np.ndarray:

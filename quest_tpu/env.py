@@ -6,7 +6,9 @@ per-backend ``createQuESTEnv`` implementations (MPI init
 there is no build-time backend fork — one environment object carries
 
 - a :class:`jax.sharding.Mesh` over the amplitude axis (``None`` = single
-  device), replacing rank/numRanks bookkeeping;
+  device), replacing rank/numRanks bookkeeping; a single-device env may
+  name its ``device``, so that replicas on one host each keep their state
+  and executables on their own chip;
 - the numeric :class:`~quest_tpu.config.Precision` (runtime, not compile-time);
 - a single ``jax.random`` key, split per draw — the analogue of the
   rank-0-seeded, broadcast mt19937 stream (``QuEST_cpu_distributed.c:1318-1329``):
@@ -15,6 +17,7 @@ there is no build-time backend fork — one environment object carries
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 import os
@@ -22,7 +25,8 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
 from .config import Precision, default_precision
 
@@ -44,6 +48,8 @@ class QuESTEnv:
     # summation (``QuEST_cpu_distributed.c:87-109``); restores
     # 1e-10-class totals/inner-products for single-precision registers
     compensated: bool = False
+    # the one device of a mesh-less env (None = JAX's default device)
+    device: Optional[jax.Device] = None
 
     @property
     def num_devices(self) -> int:
@@ -67,22 +73,40 @@ class QuESTEnv:
     def num_ranks(self) -> int:
         return self.num_devices
 
-    def sharding(self, sharded: bool = True) -> Optional[NamedSharding]:
-        """NamedSharding for a packed (2, 2^N) state array: the amplitude
+    @property
+    def devices(self) -> list:
+        """The devices this env's arrays and executables live on."""
+        if self.mesh is not None:
+            return list(self.mesh.devices.flat)
+        return [self.device if self.device is not None
+                else jax.devices()[0]]
+
+    def sharding(self, sharded: bool = True):
+        """Sharding for a packed (2, 2^N) state array: the amplitude
         axis is split on its leading (high-qubit) bits — the chunkId-prefix
         layout of ``QuEST.h:169-177`` — and the re/im plane axis is
-        replicated."""
+        replicated. A mesh-less env gives its own device's sharding, or
+        None when it names no device."""
         if self.mesh is None:
-            return None
+            return None if self.device is None \
+                else SingleDeviceSharding(self.device)
         spec = PartitionSpec(None, AMP_AXIS) if sharded else PartitionSpec()
         return NamedSharding(self.mesh, spec)
 
-    def sharding_flat(self) -> Optional[NamedSharding]:
-        """NamedSharding for a flat (2^N,) amplitude vector (jit-internal
+    def sharding_flat(self):
+        """Sharding for a flat (2^N,) amplitude vector (jit-internal
         complex form): leading bits over the mesh axis."""
         if self.mesh is None:
-            return None
+            return self.sharding()
         return NamedSharding(self.mesh, PartitionSpec(AMP_AXIS))
+
+    def default_device(self):
+        """Context that makes this env's device JAX's default, so arrays
+        built without an input (fresh sweep states, parameter vectors)
+        land on it too. A no-op for a mesh or an env without a device."""
+        if self.mesh is None and self.device is not None:
+            return jax.default_device(self.device)
+        return contextlib.nullcontext()
 
     def seed(self, seeds: Sequence[int]) -> None:
         """Re-seed the measurement RNG (``seedQuEST`` ``QuEST.h:1858``)."""
@@ -140,11 +164,14 @@ def create_quest_env(
     precision: Optional[Precision] = None,
     seed: Optional[Sequence[int]] = None,
     compensated: Optional[bool] = None,
+    device: Optional[jax.Device] = None,
 ) -> QuESTEnv:
     """Create the execution environment (``createQuESTEnv`` ``QuEST.h:785``).
 
     ``num_devices=None`` uses all local devices when more than one is present
     (as the reference's MPI build uses all ranks), else single-device.
+    ``device`` pins a single-device env to that device (one chip of a
+    multi-chip host) instead of JAX's default one.
     ``compensated=None`` enables TwoSum-compensated scalar reductions
     automatically for single precision (where naive float32 accumulation
     falls ~5 decades short of the reference's 1e-10 tolerance) and disables
@@ -160,6 +187,11 @@ def create_quest_env(
     if compensated is None:
         compensated = default_compensated(precision)
     devices = jax.devices()
+    if device is not None:
+        if num_devices not in (None, 1):
+            raise ValueError("device= names the one device of a "
+                             "single-device env; use num_devices=1")
+        num_devices = 1
     n = len(devices) if num_devices is None else num_devices
     if n > len(devices):
         raise ValueError(f"requested {n} devices but only {len(devices)} available")
@@ -169,7 +201,8 @@ def create_quest_env(
             raise ValueError("the device count must be a power of 2 "
                              "(amplitude sharding halves per device)")
         mesh = Mesh(np.asarray(devices[:n]), (AMP_AXIS,))
-    env = QuESTEnv(precision=precision, mesh=mesh, compensated=compensated)
+    env = QuESTEnv(precision=precision, mesh=mesh, compensated=compensated,
+                   device=device)
     if seed is not None:
         env.seed(seed)
     else:

@@ -17,18 +17,27 @@ import numpy as np
 
 __all__ = ["available", "load", "build_and_load", "NativeScheduler"]
 
-from .hosttag import HOST_TAG
+from .hosttag import BASE_FLAGS, HOST_TAG, build_tag
 
 
-def tagged_lib_path(base_name: str) -> str:
-    """Cache path for a native library, keyed by host/ISA fingerprint."""
-    return os.path.join(os.path.dirname(__file__),
-                        f"{base_name}.{HOST_TAG}.so")
+def _src_path(src_name: str) -> str:
+    return os.path.abspath(os.path.join(
+        os.path.dirname(__file__), os.pardir, os.pardir,
+        "native", "src", src_name))
 
 
-_LIB_PATH = tagged_lib_path("libquest_sched")
-_SRC_PATH = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
-                         "native", "src", "scheduler.cc")
+def tagged_lib_path(base_name: str, src_name: Optional[str] = None,
+                    flags: tuple[str, ...] = ()) -> str:
+    """Cache path for a native library, keyed by host/ISA fingerprint
+    and — for a library built on demand from ``src_name`` with
+    ``flags`` — by a digest of that source text and build command."""
+    tag = HOST_TAG
+    if src_name is not None and os.path.exists(_src_path(src_name)):
+        tag = build_tag(_src_path(src_name), flags)
+    return os.path.join(os.path.dirname(__file__), f"{base_name}.{tag}.so")
+
+
+_LIB_PATH = tagged_lib_path("libquest_sched", "scheduler.cc")
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
@@ -38,6 +47,8 @@ KIND_U, KIND_DIAG, KIND_U_PARAM, KIND_DIAG_PARAM = 0, 1, 2, 3
 def build_and_load(src_name: str, lib_path: str,
                    extra_flags: tuple[str, ...] = ()) -> Optional[ctypes.CDLL]:
     """Build (if absent) and dlopen one native library, or return None.
+    ``lib_path`` comes from :func:`tagged_lib_path` with the same source
+    and ``extra_flags``.
 
     Shared on-demand g++ pattern for every native component: the repo ships
     no binary artifacts, ``QUEST_TPU_NO_NATIVE=1`` disables all of them, and
@@ -46,18 +57,14 @@ def build_and_load(src_name: str, lib_path: str,
     (so clearing the variable re-enables native in-process) — this
     function only builds and loads.
     """
-    src = os.path.abspath(os.path.join(
-        os.path.dirname(__file__), os.pardir, os.pardir,
-        "native", "src", src_name))
-    stale = (os.path.exists(lib_path) and os.path.exists(src)
-             and os.path.getmtime(src) > os.path.getmtime(lib_path))
-    if not os.path.exists(lib_path) or stale:
-        # mtime invalidation: a cached .so from before a kernel change
-        # would otherwise be dlopened silently forever
+    src = _src_path(src_name)
+    if not os.path.exists(lib_path):
+        # the path carries the source digest (tagged_lib_path): a library
+        # that exists was built from exactly this source and command
         if not os.path.exists(src):
             return None
-        cmd = [os.environ.get("CXX", "g++"), "-O2", "-std=c++17", "-fPIC",
-               "-Wall", *extra_flags, "-shared", "-o", lib_path, src]
+        cmd = [os.environ.get("CXX", "g++"), *BASE_FLAGS, *extra_flags,
+               "-shared", "-o", lib_path, src]
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         except (subprocess.SubprocessError, OSError):

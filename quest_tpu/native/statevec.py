@@ -30,7 +30,12 @@ from . import build_and_load, tagged_lib_path
 
 __all__ = ["available", "load", "NativeProgram"]
 
-_LIB_PATH = tagged_lib_path("libquest_statevec")
+# -march=native is safe here: the library is JIT-built by g++ on the
+# machine it runs on (never shipped, and keyed by the host's ISA), and the
+# pair loop's contiguous inner runs are written to auto-vectorize
+_FLAGS = ("-O3", "-pthread", "-march=native")
+_LIB_PATH = tagged_lib_path("libquest_statevec", "statevec_kernel.cc",
+                            _FLAGS)
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
@@ -50,11 +55,7 @@ def load() -> Optional[ctypes.CDLL]:
         return _lib
     if _load_failed:
         return None
-    # -march=native is safe here: the library is JIT-built by g++ on the
-    # machine it runs on (never shipped), and the pair loop's contiguous
-    # inner runs are written to auto-vectorize (AVX-512 on this host)
-    lib = build_and_load("statevec_kernel.cc", _LIB_PATH,
-                         extra_flags=("-O3", "-pthread", "-march=native"))
+    lib = build_and_load("statevec_kernel.cc", _LIB_PATH, _FLAGS)
     if lib is None:
         _load_failed = True
         return None

@@ -64,20 +64,47 @@ def _split(x):
     return hi, x - hi
 
 
+# the row length of the first reduction levels (see sum_pair)
+_ROW = 1 << 20
+
+
 def sum_pair(x):
     """Compensated sum of a real array; returns the unadded (sum, err) pair
-    so callers can combine at higher precision."""
-    x = x.reshape(-1)
+    so callers can combine at higher precision.
+
+    Each level adds two halves of the array, and each level is its own
+    pass: fused, the levels form one expression over the whole input (a
+    strided even/odd pairing compiled for 11 minutes into 479 MB of TPU
+    code at 2^28 amplitudes). The last axis is viewed as rows of up to
+    2^20 and halved along the rows first, so that on a mesh every pair
+    lies on one device: whole halves of a sharded array pair amplitudes held
+    by different chips, and a 30-qubit state on four chips then asked
+    for 16 GB per chip. The input is its own pass too: fused into the
+    first level, the error-free products that feed it (``_two_prod``)
+    were contracted into FMAs on the CPU and lost their exactness."""
+    x = lax.optimization_barrier(jnp.atleast_1d(x))
     err = jnp.zeros((), dtype=x.dtype)
+    # rows along the last axis only: merging it with a leading (plane)
+    # axis would interleave the shards of a mesh
+    m = x.shape[-1]
+    row = min(m & -m, _ROW)               # a power of two dividing m
+    x = x.reshape(x.shape[:-1] + (m // row, row))
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        s, e = _two_sum(x[..., :h], x[..., h:])
+        # the e's are O(eps)·|s| each; their naive sum contributes only a
+        # second-order O(eps²·n) error to the final result
+        err = err + jnp.sum(e)
+        x = lax.optimization_barrier(s)
+    x = x.reshape(-1)
     while x.shape[0] > 1:
         n = x.shape[0]
         if n % 2:
             x = jnp.concatenate([x, jnp.zeros((1,), dtype=x.dtype)])
-        s, e = _two_sum(x[0::2], x[1::2])
-        # the e's are O(eps)·|s| each; their naive sum contributes only a
-        # second-order O(eps²·n) error to the final result
+            n += 1
+        s, e = _two_sum(x[:n // 2], x[n // 2:])
         err = err + jnp.sum(e)
-        x = s
+        x = lax.optimization_barrier(s)
     return x[0], err
 
 
@@ -89,14 +116,18 @@ def sum_compensated(x) -> jnp.ndarray:
 
 def dot_pair(a, b):
     """sum(a*b) for real arrays with exact partial products: returns the
-    (sum, err) pair. 4x the memory traffic of a naive dot — the price of
-    error-free f32 accumulation."""
+    (sum, err) pair. The four exact partial products of each element fold
+    into one rounded sum plus its error terms before the reduction — four
+    separate streams would hold four state-sized temporaries (13 GB at
+    2^28 amplitudes); the error terms are O(eps) each, so their naive sum
+    costs only a second-order error."""
     a_hi, a_lo = _split(a)
     b_hi, b_lo = _split(b)
-    streams = jnp.concatenate([
-        (a_hi * b_hi).reshape(-1), (a_hi * b_lo).reshape(-1),
-        (a_lo * b_hi).reshape(-1), (a_lo * b_lo).reshape(-1)])
-    return sum_pair(streams)
+    s1, e1 = _two_sum(a_hi * b_hi, a_hi * b_lo)
+    s2, e2 = _two_sum(a_lo * b_hi, a_lo * b_lo)
+    s, e3 = _two_sum(s1, s2)
+    total, err = sum_pair(s)
+    return total, err + jnp.sum(e1 + e2 + e3)
 
 
 def vdot_pair(a, b):

@@ -21,8 +21,10 @@ from __future__ import annotations
 
 import numpy as np
 import jax.numpy as jnp
+from jax import lax
 
-from ..core.apply import split_shape
+from ..core.apply import (LANE_BITS, _SWAP, apply_diagonal, apply_unitary,
+                          split_shape)
 
 __all__ = [
     "multi_rotate_z_diag",
@@ -30,6 +32,7 @@ __all__ = [
     "calc_total_prob",
     "calc_inner_product",
     "calc_prob_of_outcome",
+    "zero_outcome_part",
     "collapse_to_known_prob_outcome",
     "set_weighted",
 ]
@@ -47,11 +50,9 @@ def multi_rotate_z_diag(k: int, angle: float) -> np.ndarray:
 
 
 def swap_amps(state, num_qubits, q1, q2):
-    """SWAP via axis transpose — pure data movement, no arithmetic
-    (vs ``statevec_swapQubitAmps`` ``QuEST_cpu.c:3502``)."""
-    hi, lo = max(q1, q2), min(q1, q2)
-    shape = split_shape(num_qubits, (hi, lo))
-    return state.reshape(shape).transpose(0, 3, 2, 1, 4).reshape(-1)
+    """SWAP as pure data movement — its unit entries become slice moves,
+    no arithmetic (vs ``statevec_swapQubitAmps`` ``QuEST_cpu.c:3502``)."""
+    return apply_unitary(state, num_qubits, _SWAP, (q1, q2))
 
 
 # ---------------------------------------------------------------------------
@@ -72,13 +73,33 @@ def calc_inner_product(bra, ket) -> jnp.ndarray:
     return jnp.vdot(bra, ket)
 
 
+def zero_outcome_part(x, num_qubits: int, qubit: int):
+    """The amplitudes of ``x`` (last axis) whose ``qubit`` bit is 0, for a
+    reduction: on the ``(rows, 128)`` view (core/apply.py, "Lane qubits"),
+    with the other amplitudes zeroed by an ``iota`` mask over the lanes or
+    the rows. A slice of the split axis would leave a minor dimension of
+    ``2^qubit`` for a lane qubit, and for the top qubit of 28 its
+    compensated sum compiled for 12 minutes on a TPU."""
+    lead = x.shape[:-1]
+    if num_qubits < LANE_BITS:
+        pre, _, post = split_shape(num_qubits, (qubit,))
+        return x.reshape(lead + (pre, 2, post))[..., 0, :]
+    v = x.reshape(lead + (-1, 1 << LANE_BITS))
+    if qubit < LANE_BITS:
+        i = lax.broadcasted_iota(jnp.int32, (1, 1 << LANE_BITS), 1)
+        keep = ((i >> qubit) & 1) == 0
+    else:
+        i = lax.broadcasted_iota(jnp.int32, (v.shape[-2], 1), 0)
+        keep = ((i >> (qubit - LANE_BITS)) & 1) == 0
+    return jnp.where(keep, v, jnp.zeros((), v.dtype))
+
+
 def calc_prob_of_outcome(state, num_qubits: int, qubit: int, outcome: int) -> jnp.ndarray:
     """P(outcome 0) summed directly; P(outcome 1) as its complement 1-P0 —
     the reference's exact semantics (``statevec_calcProbOfOutcome``
     ``QuEST_cpu_local.c:279-285``), observable on unnormalised registers
     (debug state): summing the outcome-1 amplitudes would differ."""
-    shape = split_shape(num_qubits, (qubit,))
-    sub = state.reshape(shape)[:, 0, :]
+    sub = zero_outcome_part(state, num_qubits, qubit)
     zero_prob = jnp.sum(jnp.real(sub) ** 2 + jnp.imag(sub) ** 2)
     return zero_prob if outcome == 0 else 1.0 - zero_prob
 
@@ -86,10 +107,9 @@ def calc_prob_of_outcome(state, num_qubits: int, qubit: int, outcome: int) -> jn
 def collapse_to_known_prob_outcome(state, num_qubits, qubit, outcome, prob):
     """Zero the non-outcome half, renormalise the outcome half by 1/sqrt(prob)
     (``QuEST_cpu.c:3346-3494``). ``prob`` may be traced."""
-    shape = split_shape(num_qubits, (qubit,))
     renorm = (1.0 / jnp.sqrt(prob)).astype(state.dtype)
-    fac = jnp.zeros((1, 2, 1), dtype=state.dtype).at[0, outcome, 0].set(renorm)
-    return (state.reshape(shape) * fac).reshape(-1)
+    fac = jnp.zeros((2,), dtype=state.dtype).at[outcome].set(renorm)
+    return apply_diagonal(state, num_qubits, (qubit,), fac)
 
 
 def set_weighted(fac1, state1, fac2, state2, fac_out, out):

@@ -44,7 +44,8 @@ import numpy as np
 import jax.numpy as jnp
 from jax import lax
 
-from ..core.apply import apply_unitary, apply_diagonal
+from ..core.apply import (apply_diagonal, apply_unitary, pass_boundary,
+                          transpose_qubits)
 
 __all__ = ["ExchangePlan", "plan_exchange", "run_exchange",
            "apply_op_local", "apply_1q_cross_shard",
@@ -187,16 +188,20 @@ def run_exchange(local: jnp.ndarray, plan: ExchangePlan,
     """Execute one relayout on the per-device chunk (shard_map-internal)."""
     lt = plan.local_top
     if plan.pre_axes is not None:
-        local = local.reshape((2,) * lt).transpose(plan.pre_axes).reshape(-1)
+        local = transpose_qubits(local, lt, plan.pre_axes)
     if plan.k:
         y = local.reshape(1 << plan.k, -1)
         y = lax.all_to_all(y, axis_name, 0, 0,
                            axis_index_groups=plan.groups, tiled=True)
-        local = y.reshape(-1)
+        # each step its own pass: an all_to_all fused with the gate or
+        # swap after it compiled for 84 s at 2^28 amplitudes per chip,
+        # 3.5 s with the boundary (core/apply.py pass_boundary)
+        local = pass_boundary(y.reshape(-1))
     if plan.device_perm is not None:
-        local = lax.ppermute(local, axis_name, plan.device_perm)
+        local = pass_boundary(lax.ppermute(local, axis_name,
+                                           plan.device_perm))
     if plan.post_axes is not None:
-        local = local.reshape((2,) * lt).transpose(plan.post_axes).reshape(-1)
+        local = transpose_qubits(local, lt, plan.post_axes)
     return local
 
 
@@ -311,7 +316,7 @@ def run_exchange_overlapped(local: jnp.ndarray, plan: ExchangePlan,
     lt = plan.local_top
     k = plan.k
     if plan.pre_axes is not None:
-        local = local.reshape((2,) * lt).transpose(plan.pre_axes).reshape(-1)
+        local = transpose_qubits(local, lt, plan.pre_axes)
     y = local.reshape(1 << k, -1)
     nslabs = 1 << slab_bits
     m = y.shape[1] // nslabs
@@ -346,7 +351,7 @@ def apply_1q_cross_shard(local: jnp.ndarray, u: jnp.ndarray, position: int,
     lt = local_top
     j = position - lt
     pairs = tuple((v, v ^ (1 << j)) for v in range(1 << shard_bits))
-    other = lax.ppermute(local, axis_name, pairs)
+    other = pass_boundary(lax.ppermute(local, axis_name, pairs))
     idx = lax.axis_index(axis_name)
     r = (idx >> j) & 1
     u = jnp.asarray(u, dtype=local.dtype)

@@ -426,17 +426,17 @@ def apply_relayout(state: jnp.ndarray, num_qubits: int,
                    perm_before: np.ndarray, perm_after: np.ndarray,
                    sharding=None) -> jnp.ndarray:
     """Move the qubit at physical position ``perm_before[l]`` to
-    ``perm_after[l]``: one transpose of the ``(2,)*n`` view. Across the
-    sharded boundary XLA lowers this to an all-to-all over the mesh — the
-    single fused data movement replacing the reference's per-qubit
-    ``statevec_swapQubitAmps`` exchanges.
+    ``perm_after[l]``: a transpose of the ``(2,)*n`` view, applied as
+    qubit swaps (:func:`~quest_tpu.core.apply.transpose_qubits`). Across
+    the sharded boundary XLA lowers this to an all-to-all over the mesh.
     """
     n = num_qubits
     # axis index of physical position p is n-1-p (C-order, high bit first)
     src_axis_of_dst = np.empty(n, dtype=np.int64)
     for l in range(n):
         src_axis_of_dst[n - 1 - int(perm_after[l])] = n - 1 - int(perm_before[l])
-    out = state.reshape((2,) * n).transpose(tuple(src_axis_of_dst)).reshape(-1)
+    from ..core.apply import transpose_qubits
+    out = transpose_qubits(state, n, tuple(src_axis_of_dst))
     if sharding is not None:
         out = jax.lax.with_sharding_constraint(out, sharding)
     return out

@@ -33,7 +33,7 @@ import numpy as np
 import jax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 from ..core.packing import pack, unpack
 from ..env import AMP_AXIS
 from ..resilience import faults as _faults

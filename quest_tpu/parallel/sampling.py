@@ -32,7 +32,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 from ..env import AMP_AXIS
 
 __all__ = ["sample_sharded", "sample_batched", "sample_mixture",

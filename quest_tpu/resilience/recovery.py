@@ -55,8 +55,8 @@ _FATAL_TYPES = (ValueError, TypeError, KeyError, IndexError,
 def classify(exc: BaseException) -> str:
     """``"transient"`` | ``"poison"`` | ``"fatal"`` for one executor
     exception. Unknown ``Exception`` subclasses default to transient —
-    the runtime's failure modes (XLA ``XlaRuntimeError``, RPC resets on
-    tunneled backends) are RuntimeError-shaped, while the fatal set is
+    the runtime's failure modes (XLA ``XlaRuntimeError``, runtime
+    resets) are RuntimeError-shaped, while the fatal set is
     the closed family of caller errors."""
     if isinstance(exc, NumericalFault):
         return PRECISION if exc.kind == "precision" else POISON

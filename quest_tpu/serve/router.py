@@ -88,7 +88,7 @@ def replica_envs(num_replicas: int,
 
     ``devices_per_replica=None`` splits the device pool evenly (largest
     power of two that fits); ``1`` makes single-device replicas
-    (``mesh=None``); ``k>1`` gives each replica a ``k``-device
+    (``mesh=None``, each env naming its own device); ``k>1`` gives each replica a ``k``-device
     amplitude-sharding mesh. When the pool is too small for disjoint
     slices (e.g. plain CPU), every replica shares the SAME first-``k``
     devices — the full-mesh-replica test mode: the failure domains are
@@ -120,7 +120,8 @@ def replica_envs(num_replicas: int,
         devs = devices[i * k:(i + 1) * k] if disjoint else devices[:k]
         mesh = Mesh(np.asarray(devs), (AMP_AXIS,)) if k > 1 else None
         env = QuESTEnv(precision=precision, mesh=mesh,
-                       compensated=compensated)
+                       compensated=compensated,
+                       device=devs[0] if k == 1 else None)
         if seed is not None:
             env.seed(list(seed) + [i])
         else:

@@ -6,6 +6,7 @@ rather than failing the install. (The runtime also builds it on demand at
 first import; see quest_tpu/native/__init__.py.)
 """
 
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -24,13 +25,13 @@ class BuildWithNative(build_py):
             root / "quest_tpu" / "native" / "hosttag.py")
         hosttag = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(hosttag)
-        out = (root / "quest_tpu" / "native"
-               / f"libquest_sched.{hosttag.HOST_TAG}.so")
         if src.exists():
+            out = (root / "quest_tpu" / "native"
+                   / f"libquest_sched.{hosttag.build_tag(str(src))}.so")
             try:
                 subprocess.run(
-                    ["g++", "-O2", "-std=c++17", "-fPIC", "-Wall", "-shared",
-                     "-o", str(out), str(src)],
+                    [os.environ.get("CXX", "g++"), *hosttag.BASE_FLAGS,
+                     "-shared", "-o", str(out), str(src)],
                     check=True, timeout=300)
             except (subprocess.SubprocessError, OSError) as e:
                 print(f"warning: native scheduler build skipped ({e}); "

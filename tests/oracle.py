@@ -45,6 +45,32 @@ def apply_sv(psi, n, u, targets, controls=(), control_states=None):
     return full_operator(n, u, targets, controls, control_states) @ psi
 
 
+def apply_gate(psi, n, u, targets, controls=(), control_states=None):
+    """:func:`apply_sv` without the dense ``2^n x 2^n`` operator: the same
+    map as a contraction on the ``(2,)*n`` tensor (qubit ``q`` is axis
+    ``n-1-q``), for registers too wide for :func:`full_operator`."""
+    u = np.asarray(u, dtype=np.complex128)
+    k = len(targets)
+    if control_states is None:
+        control_states = [1] * len(controls)
+    t = np.asarray(psi, dtype=np.complex128).reshape((2,) * n)
+    idx = [slice(None)] * n
+    for c, s in zip(controls, control_states):
+        idx[n - 1 - c] = s
+    idx = tuple(idx)
+    sub = t[idx]
+    remaining = [q for q in reversed(range(n)) if q not in controls]
+    # u's index bit j addresses targets[j]: as a (2,)*2k tensor its axes
+    # run from targets[k-1] down to targets[0], outputs then inputs
+    in_axes = [remaining.index(targets[j]) for j in reversed(range(k))]
+    res = np.tensordot(u.reshape((2,) * (2 * k)), sub,
+                       axes=(list(range(k, 2 * k)), in_axes))
+    res = np.moveaxis(res, list(range(k)), in_axes)
+    out = t.copy()
+    out[idx] = res
+    return out.reshape(-1)
+
+
 def apply_dm(rho, n, u, targets, controls=(), control_states=None):
     full = full_operator(n, u, targets, controls, control_states)
     return full @ rho @ full.conj().T

@@ -23,7 +23,7 @@ import pytest
 
 import quest_tpu as qt
 from quest_tpu.circuits import Circuit
-from quest_tpu.compat import shard_map
+from jax import shard_map
 from quest_tpu.core.apply import apply_unitary
 from quest_tpu.env import AMP_AXIS
 from quest_tpu.parallel.exchange import (plan_exchange, run_exchange,
@@ -131,7 +131,7 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 import quest_tpu as qt
 from quest_tpu.circuits import Circuit
-from quest_tpu.compat import shard_map
+from jax import shard_map
 from quest_tpu.algorithms import qft
 
 env = qt.createQuESTEnv(num_devices=8, seed=[7])
@@ -257,7 +257,7 @@ import jax.numpy as jnp
 import numpy as np
 import quest_tpu as qt
 from quest_tpu.circuits import Circuit
-from quest_tpu.compat import shard_map
+from jax import shard_map
 
 env = qt.createQuESTEnv(num_devices=8, seed=[7])
 

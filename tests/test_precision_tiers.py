@@ -199,7 +199,7 @@ class TestTierKeyedCaches:
         f_fast = cc._warm_form_key("sweep", "none", FAST_TIER)
         f_single = cc._warm_form_key("sweep", "none", SINGLE_TIER)
         assert len({f_env, f_fast, f_single}) == 3
-        wc = WarmCache(str(tmp_path), install_xla_cache=False)
+        wc = WarmCache(str(tmp_path))
         shapes = ((2, 16), (4, 0))
         keys = {wc._key(cc, f, shapes)
                 for f in (f_env, f_fast, f_single)}

@@ -15,6 +15,7 @@ import os
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -99,6 +100,65 @@ class TestReplicaEnvs:
             replica_envs(0)
         with pytest.raises(ValueError):
             replica_envs(2, devices_per_replica=3)
+
+
+class TestReplicaPlacement:
+    """One-device replica envs name their own device: state, compiled
+    executables and served batches stay on it."""
+
+    def test_replicas_keep_state_and_executables_on_their_device(self):
+        envs = replica_envs(4, devices_per_replica=1, seed=[3])
+        devices = [e.device for e in envs]
+        assert len({d.id for d in devices}) == 4
+        c = _hea(4)
+        for env in envs:
+            q = qt.createQureg(4, env)
+            qt.initZeroState(q)
+            assert q.state.devices() == {env.device}
+            cc = c.compile(env).precompile()
+            cc.run(q, {nm: 0.1 for nm in cc.param_names})
+            assert q.state.devices() == {env.device}
+            assert cc._aot.output_shardings.device_set == {env.device}
+
+    def test_service_batches_run_on_the_env_device(self, rng):
+        env = replica_envs(4, devices_per_replica=1, seed=[3])[3]
+        c = _hea(4)
+        ham = _z_ham(4)
+        pm = rng.uniform(-np.pi, np.pi, size=(3, len(c.param_names)))
+        with SimulationService(env, warm_cache=False,
+                               perf_ledger=False) as svc:
+            cc = svc.warm(c, batch_sizes=(4,), observables=ham)
+            got = [svc.submit(cc, row, observables=ham).result(timeout=60)
+                   for row in pm]
+            with env.default_device():
+                out = cc.sweep(np.asarray(pm))
+        assert out.devices() == {env.device}
+        np.testing.assert_allclose(got, _oracle_energies(c, pm, ham),
+                                   atol=1e-12)
+
+    def test_warm_restart_loads_onto_its_own_device(self, tmp_path, rng):
+        c = _hea(4, ring=False)
+        ham = _z_ham(4)
+        pm = rng.uniform(-np.pi, np.pi, size=(4, len(c.param_names)))
+        cache = WarmCache(str(tmp_path / "warm"))
+        for boot in range(2):
+            # a fresh env each boot, on the same (non-default) device —
+            # what a supervised restart builds
+            env = replica_envs(4, devices_per_replica=1, seed=[7])[2]
+            with SimulationService(env, warm_cache=cache,
+                                   perf_ledger=False) as svc:
+                cc = svc.warm(c, batch_sizes=(4,), observables=ham)
+                got = [svc.submit(cc, row, observables=ham)
+                       .result(timeout=60) for row in pm]
+            loaded = list(cc._batched_aot.values())
+            assert loaded
+            for exe in loaded:
+                for sh in jax.tree.leaves(exe.output_shardings):
+                    assert sh.device_set == {env.device}
+            np.testing.assert_allclose(got, _oracle_energies(c, pm, ham),
+                                       atol=1e-12)
+        st = cache.stats()
+        assert st["misses"] == 1 and st["hits"] == 1, st
 
 
 class TestRouterOracle:
