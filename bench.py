@@ -1886,7 +1886,7 @@ def bench_precision_tiers(qt, env, platform: str) -> dict:
 def _profiler_doc(site: str, tier=None) -> dict:
     """The PR-12 dispatch profiler's per-key document for ``site`` (and
     optionally ``tier``) from the CURRENT snapshot — the live
-    roofline_frac / achieved-GB/s attribution the mxu rows carry."""
+    achieved-GB/s attribution the mxu rows carry."""
     from quest_tpu.telemetry import profile as _tprof
     snap = _tprof.profiler().snapshot()
     for doc in snap["keys"].values():
@@ -1895,9 +1895,8 @@ def _profiler_doc(site: str, tier=None) -> dict:
     return {}
 
 
-def _roofline_fields(doc: dict) -> dict:
+def _achieved_fields(doc: dict) -> dict:
     return {
-        "roofline_frac": round(float(doc.get("roofline_frac", 0.0)), 4),
         "achieved_gb_per_s": round(
             float(doc.get("achieved_bytes_per_s", 0.0)) / 1e9, 3),
     }
@@ -1919,7 +1918,7 @@ def bench_mxu_saturation(qt, env, platform: str) -> list:
        path entirely) vs ONE batched engine executable
        (``sweep(tier='quad')``).
 
-    Every on-row carries the live ``roofline_frac`` + achieved-GB/s of
+    Every on-row carries the live achieved-GB/s of
     its dispatch key from the PR-12 profiler (sample rate 1.0 for the
     measured pass), plus a parity figure — never-worse selection means
     zero tolerated accuracy loss. On CPU the Pallas pairs run
@@ -1995,7 +1994,7 @@ def bench_mxu_saturation(qt, env, platform: str) -> list:
         rows.append({
             "metric": f"mxu fusion off (lane/VPU row kernels), {label}",
             "value": round(batch / dt_off, 2), "unit": "points/sec",
-            **_roofline_fields(doc_off),
+            **_achieved_fields(doc_off),
         })
         rows.append({
             "metric": f"mxu fusion on (MXU-shaped fused contractions), "
@@ -2004,7 +2003,7 @@ def bench_mxu_saturation(qt, env, platform: str) -> list:
             "speedup_vs_off": round(dt_off / max(dt_on, 1e-12), 3),
             "rowmxu_stages": mxu_stages,
             "max_amp_deviation": dev,
-            **_roofline_fields(doc_on),
+            **_achieved_fields(doc_on),
         })
 
         # -- 2: Pallas trajectory waves vs the plain-XLA wave loop -----
@@ -2039,7 +2038,7 @@ def bench_mxu_saturation(qt, env, platform: str) -> list:
                       f"loop), {tlabel}",
             "value": round(ntraj / dt_toff, 2),
             "unit": "trajectories/sec",
-            **_roofline_fields(doc_toff),
+            **_achieved_fields(doc_toff),
         })
         rows.append({
             "metric": f"trajectory waves pallas-on (fused layer + fused "
@@ -2050,7 +2049,7 @@ def bench_mxu_saturation(qt, env, platform: str) -> list:
             "fused_items": fused,
             "mean_deviation_sigma": round(
                 abs(m_on - m_off) / max(e_on + e_off, 1e-12), 3),
-            **_roofline_fields(doc_ton),
+            **_achieved_fields(doc_ton),
         })
 
         # -- 3: batched QUAD-dd engine vs the per-point dd loop --------
@@ -2114,7 +2113,7 @@ def bench_mxu_saturation(qt, env, platform: str) -> list:
                 "speedup_vs_off": round(dt_soff / max(dt_son, 1e-12), 3),
                 "max_amp_deviation": dev_dd,
                 "host_syncs": 1,
-                **_roofline_fields(doc_dd),
+                **_achieved_fields(doc_dd),
             })
         finally:
             if not x64_was:
@@ -2470,9 +2469,8 @@ def _bench_profiler_overhead(qt, env, platform: str) -> list:
     by the measured per-request service time — the number the <1%
     budget structurally guarantees. The on-row also reports the live
     per-key attribution the profiler produced (profiled keys, the
-    serving key's roofline_frac) — the acceptance signal that every
-    mode now has a live roofline number, not just this file's offline
-    ones."""
+    serving key's p99) — the acceptance signal that every mode is
+    profiled live."""
     from quest_tpu.serve import SimulationService
     from quest_tpu.telemetry import profile as _profile
     num_qubits = int(os.environ.get("QUEST_BENCH_PROF_QUBITS", "16"))
@@ -2527,10 +2525,6 @@ def _bench_profiler_overhead(qt, env, platform: str) -> list:
             prof_stats.update({
                 "profiled_keys": len(snap["keys"]),
                 "dispatches_sampled": snap["dispatches_sampled"],
-                "roofline_model": snap["roofline_model"],
-                "serve_roofline_frac": round(max(
-                    (v["roofline_frac"] for v in serve_keys),
-                    default=0.0), 6),
                 "serve_p99_s": round(max(
                     (v["p99_s"] for v in serve_keys), default=0.0), 6),
                 "drift_models": sorted(

@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import os
 import threading
 import warnings
@@ -127,6 +128,14 @@ def _wire_cmat(arr) -> dict:
     # caller's host matrix, recorded before any device work)
     a = np.asarray(arr, dtype=np.complex128)
     return {"re": a.real.tolist(), "im": a.imag.tolist()}
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def zero_state(num_qubits: int, dtype) -> jnp.ndarray:
+    """Packed ``(2, 2^n)`` planes of |0..0>: the shared start state of
+    the sweep forms, built on the device as one named executable
+    (``jit_zero_state`` in a device trace)."""
+    return jnp.zeros((2, 1 << num_qubits), dtype=dtype).at[0, 0].set(1.0)
 
 
 def _phase_diag(angle) -> jnp.ndarray:
@@ -2169,7 +2178,7 @@ class CompiledCircuit:
         offline rooflines use), times the batch rows, plus one gather
         pass per Pauli term for energy dispatches. The dispatch
         profiler divides this by measured wall-to-ready seconds for a
-        live achieved-bytes/s and roofline_frac per key."""
+        live achieved-bytes/s per key."""
         itemsize = np.dtype(self.env.precision.real_dtype).itemsize
         state_bytes = 4.0 * itemsize * (1 << self.num_qubits)
         passes = max(self.plan.num_dispatches, 1) + max(int(terms), 0)
@@ -3191,55 +3200,54 @@ class CompiledCircuit:
                 f"{kind}_sweep runs on statevector-compiled programs "
                 "(Trotter rotations act on ket amplitudes); evolve "
                 "density registers through their channel circuits")
-        tier = self._effective_tier(tier)
-        if tier is not None and tier.name == "quad":
-            raise ValueError(
-                f"{kind}_sweep cannot run at the QUAD tier: the "
-                "double-double walk has no scan-resident Trotter "
-                "form; use tier='double' for the highest rung")
-        nq, T, xm, ym, zm, coeffs = self._pauli_operands(hamiltonian)
-        n = self.num_qubits
-        pm = self._validated_param_matrix(param_matrix)
-        # fault injection for dynamics dispatches happens at the
-        # serving boundary ("serve.evolve" in faults.SITES) — the
-        # circuits layer contributes the profiling span and trace
-        # annotation only
-        sp = _profile.profile_dispatch(f"circuits.{kind}_sweep")
-        B = pm.shape[0]
-        pol = self._batch_policy(B)
-        mode = pol["mode"]
-        pm_run, B = self._padded_params(pm, mode)
-        pm_run = self._place_batch(pm_run, mode)
-        if state_f is None:
-            state_f = jnp.zeros((2, 1 << n),
-                                dtype=self.env.precision.real_dtype
-                                ).at[0, 0].set(1.0)
-        elif getattr(state_f, "shape", None) != (2, 1 << n):
-            raise ValueError(
-                f"{kind}_sweep state_f must be shared (2, {1 << n}) "
-                f"planes; got {getattr(state_f, 'shape', None)}")
-        else:
-            state_f = jnp.asarray(
-                state_f, dtype=self.env.precision.real_dtype)
-        if kind == "evolve":
-            S = int(spec.steps)
-            fn = self._evolve_fn(mode, tier, steps=S,
-                                 order=int(spec.order))
-            knob = jnp.asarray(spec.dt,
-                               dtype=self.env.precision.real_dtype)
-        else:
-            S = int(spec.steps)
-            fn = self._ground_fn(mode, tier, steps=S,
-                                 method=str(spec.method))
-            knob = jnp.asarray(spec.tau,
-                               dtype=self.env.precision.real_dtype)
-        args = (state_f, pm_run, jnp.asarray(xm), jnp.asarray(ym),
-                jnp.asarray(zm),
-                jnp.asarray(coeffs,
-                            dtype=self.env.precision.real_dtype), knob)
-        ann_name = (f"quest_tpu.circuits.{kind}_sweep:"
-                    f"b{pm_run.shape[0]}:t{T}:s{S}:"
-                    f"{tier.name if tier is not None else 'env'}")
+        with dispatch_annotation("quest_tpu.circuits.prepare"):
+            tier = self._effective_tier(tier)
+            if tier is not None and tier.name == "quad":
+                raise ValueError(
+                    f"{kind}_sweep cannot run at the QUAD tier: the "
+                    "double-double walk has no scan-resident Trotter "
+                    "form; use tier='double' for the highest rung")
+            nq, T, xm, ym, zm, coeffs = self._pauli_operands(hamiltonian)
+            n = self.num_qubits
+            pm = self._validated_param_matrix(param_matrix)
+            # fault injection for dynamics dispatches happens at the
+            # serving boundary ("serve.evolve" in faults.SITES) — the
+            # circuits layer contributes the profiling span and trace
+            # annotation only
+            sp = _profile.profile_dispatch(f"circuits.{kind}_sweep")
+            B = pm.shape[0]
+            pol = self._batch_policy(B)
+            mode = pol["mode"]
+            pm_run, B = self._padded_params(pm, mode)
+            pm_run = self._place_batch(pm_run, mode)
+            if state_f is None:
+                state_f = zero_state(n, self.env.precision.real_dtype)
+            elif getattr(state_f, "shape", None) != (2, 1 << n):
+                raise ValueError(
+                    f"{kind}_sweep state_f must be shared (2, {1 << n}) "
+                    f"planes; got {getattr(state_f, 'shape', None)}")
+            else:
+                state_f = jnp.asarray(
+                    state_f, dtype=self.env.precision.real_dtype)
+            if kind == "evolve":
+                S = int(spec.steps)
+                fn = self._evolve_fn(mode, tier, steps=S,
+                                     order=int(spec.order))
+                knob = jnp.asarray(spec.dt,
+                                   dtype=self.env.precision.real_dtype)
+            else:
+                S = int(spec.steps)
+                fn = self._ground_fn(mode, tier, steps=S,
+                                     method=str(spec.method))
+                knob = jnp.asarray(spec.tau,
+                                   dtype=self.env.precision.real_dtype)
+            args = (state_f, pm_run, jnp.asarray(xm), jnp.asarray(ym),
+                    jnp.asarray(zm),
+                    jnp.asarray(coeffs,
+                                dtype=self.env.precision.real_dtype), knob)
+            ann_name = (f"quest_tpu.circuits.{kind}_sweep:"
+                        f"b{pm_run.shape[0]}:t{T}:s{S}:"
+                        f"{tier.name if tier is not None else 'env'}")
         with dispatch_annotation(ann_name):
             out = fn(*args)
         # the stepping client pays one dispatch + one transfer per
@@ -3444,41 +3452,40 @@ class CompiledCircuit:
         compile-time tier, else the env precision) — the serving layer
         passes per-request tiers against one compiled program, and each
         tier compiles and caches its OWN executable."""
-        tier = self._effective_tier(tier)
-        pm = self._validated_param_matrix(param_matrix)
-        sp = _profile.profile_dispatch("circuits.sweep")
-        poison = _faults.fire("circuits.sweep")
-        n = self.num_qubits
-        B = pm.shape[0]
-        pol = self._batch_policy(B)
-        mode = pol["mode"]
-        pm_run, B = self._padded_params(pm, mode)
-        pm_run = self._place_batch(pm_run, mode)
-        # ONE annotation label for both dispatch branches (profiler
-        # span names must group); annotations are built fresh per
-        # entry — a TraceMe must not be re-entered after exit
-        ann_name = (f"quest_tpu.circuits.sweep:b{pm_run.shape[0]}:"
-                    f"{tier.name if tier is not None else 'env'}")
-        # coerce BEFORE shape-dispatching: a nested list has no .ndim,
-        # and a wrong-width or wrong-dtype shared state must fail here
-        # with a shaped error, not deep inside the trace
-        if state_f is not None:
-            state_f = jnp.asarray(state_f,
-                                  dtype=self.env.precision.real_dtype)
-            if state_f.ndim not in (2, 3):
-                raise ValueError(
-                    f"state_f must be shared (2, {1 << n}) planes or an "
-                    f"owned (batch, 2, {1 << n}) batch; got shape "
-                    f"{state_f.shape}")
-            if state_f.ndim == 2 and state_f.shape != (2, 1 << n):
-                raise ValueError(
-                    f"shared state_f must be (2, {1 << n}); got "
-                    f"{state_f.shape}")
-        if state_f is None or state_f.ndim == 2:
+        with dispatch_annotation("quest_tpu.circuits.prepare"):
+            tier = self._effective_tier(tier)
+            pm = self._validated_param_matrix(param_matrix)
+            sp = _profile.profile_dispatch("circuits.sweep")
+            poison = _faults.fire("circuits.sweep")
+            n = self.num_qubits
+            B = pm.shape[0]
+            pol = self._batch_policy(B)
+            mode = pol["mode"]
+            pm_run, B = self._padded_params(pm, mode)
+            pm_run = self._place_batch(pm_run, mode)
+            # ONE annotation label for both dispatch branches (profiler
+            # span names must group); annotations are built fresh per
+            # entry — a TraceMe must not be re-entered after exit
+            ann_name = (f"quest_tpu.circuits.sweep:b{pm_run.shape[0]}:"
+                        f"{tier.name if tier is not None else 'env'}")
+            # coerce BEFORE shape-dispatching: a nested list has no .ndim,
+            # and a wrong-width or wrong-dtype shared state must fail here
+            # with a shaped error, not deep inside the trace
+            if state_f is not None:
+                state_f = jnp.asarray(state_f,
+                                      dtype=self.env.precision.real_dtype)
+                if state_f.ndim not in (2, 3):
+                    raise ValueError(
+                        f"state_f must be shared (2, {1 << n}) planes or an "
+                        f"owned (batch, 2, {1 << n}) batch; got shape "
+                        f"{state_f.shape}")
+                if state_f.ndim == 2 and state_f.shape != (2, 1 << n):
+                    raise ValueError(
+                        f"shared state_f must be (2, {1 << n}); got "
+                        f"{state_f.shape}")
             if state_f is None:
-                state_f = jnp.zeros((2, 1 << n),
-                                    dtype=self.env.precision.real_dtype
-                                    ).at[0, 0].set(1.0)
+                state_f = zero_state(n, self.env.precision.real_dtype)
+        if state_f.ndim == 2:
             form = self._warm_form_key("sweep", mode, tier)
             aot = self._aot_lookup(form, (state_f, pm_run))
             out = None
@@ -3541,43 +3548,42 @@ class CompiledCircuit:
         channels. ``tier`` as in :meth:`sweep`; compensated tiers
         additionally run each Pauli term through the pair-path
         reduction."""
-        tier = self._effective_tier(tier)
-        nq, T, xm, ym, zm, coeffs = self._pauli_operands(hamiltonian)
-        n = self.num_qubits
+        with dispatch_annotation("quest_tpu.circuits.prepare"):
+            tier = self._effective_tier(tier)
+            nq, T, xm, ym, zm, coeffs = self._pauli_operands(hamiltonian)
+            n = self.num_qubits
 
-        pm = self._validated_param_matrix(param_matrix)
-        sp = _profile.profile_dispatch("circuits.expectation_sweep")
-        poison = _faults.fire("circuits.expectation_sweep")
-        if poison == "precision":
-            # energies carry no unit-norm invariant for any monitor to
-            # check, so a drifted energy would be UNDETECTABLE silent
-            # corruption — degrade the injected fault to the NaN form
-            # the screens catch (same rule as the serving boundary)
-            poison = "nan"
-        B = pm.shape[0]
-        pol = self._batch_policy(B)
-        mode = pol["mode"]
-        pm_run, B = self._padded_params(pm, mode)
-        pm_run = self._place_batch(pm_run, mode)
+            pm = self._validated_param_matrix(param_matrix)
+            sp = _profile.profile_dispatch("circuits.expectation_sweep")
+            poison = _faults.fire("circuits.expectation_sweep")
+            if poison == "precision":
+                # energies carry no unit-norm invariant for any monitor to
+                # check, so a drifted energy would be UNDETECTABLE silent
+                # corruption — degrade the injected fault to the NaN form
+                # the screens catch (same rule as the serving boundary)
+                poison = "nan"
+            B = pm.shape[0]
+            pol = self._batch_policy(B)
+            mode = pol["mode"]
+            pm_run, B = self._padded_params(pm, mode)
+            pm_run = self._place_batch(pm_run, mode)
 
-        fn = self._energy_fn(mode, tier)
-        if state_f is None:
-            state_f = jnp.zeros((2, 1 << n),
-                                dtype=self.env.precision.real_dtype
-                                ).at[0, 0].set(1.0)
-        elif getattr(state_f, "shape", None) != (2, 1 << n):
-            # the energy executable broadcasts ONE shared start state; a
-            # (B, 2, 2^n) batch would silently mis-unpack deep in the
-            # trace — reject it at the boundary
-            raise ValueError(
-                f"expectation_sweep state_f must be shared (2, {1 << n}) "
-                f"planes; got {getattr(state_f, 'shape', None)} (run "
-                "batched planes through sweep(), then reduce)")
-        args = (state_f, pm_run, jnp.asarray(xm), jnp.asarray(ym),
-                jnp.asarray(zm),
-                jnp.asarray(coeffs, dtype=self.env.precision.real_dtype))
-        aot = self._aot_lookup(self._warm_form_key("energy", mode, tier),
-                               args)
+            fn = self._energy_fn(mode, tier)
+            if state_f is None:
+                state_f = zero_state(n, self.env.precision.real_dtype)
+            elif getattr(state_f, "shape", None) != (2, 1 << n):
+                # the energy executable broadcasts ONE shared start state; a
+                # (B, 2, 2^n) batch would silently mis-unpack deep in the
+                # trace — reject it at the boundary
+                raise ValueError(
+                    f"expectation_sweep state_f must be shared (2, {1 << n}) "
+                    f"planes; got {getattr(state_f, 'shape', None)} (run "
+                    "batched planes through sweep(), then reduce)")
+            args = (state_f, pm_run, jnp.asarray(xm), jnp.asarray(ym),
+                    jnp.asarray(zm),
+                    jnp.asarray(coeffs, dtype=self.env.precision.real_dtype))
+            aot = self._aot_lookup(self._warm_form_key("energy", mode, tier),
+                                   args)
         out = None
         ann_name = (f"quest_tpu.circuits.expectation_sweep:"
                     f"b{pm_run.shape[0]}:t{T}:"
@@ -3649,48 +3655,47 @@ class CompiledCircuit:
 
         Returns ``(values, grads)``: ``(B,)`` and ``(B, P)`` arrays.
         """
-        tier = self._grad_tier(tier)
-        nparams = len(self.param_names)
-        if nparams == 0:
-            raise ValueError(
-                "this circuit declares no parameters; there is nothing "
-                "to differentiate (record angles via "
-                "Circuit.parameter / Param placeholders)")
-        nq, T, xm, ym, zm, coeffs = self._pauli_operands(hamiltonian)
-        n = self.num_qubits
-        pm = self._validated_param_matrix(param_matrix)
-        sp = _profile.profile_dispatch("circuits.grad_sweep")
-        poison = _faults.fire("circuits.grad_sweep")
-        if poison == "precision":
-            # gradients carry no unit-norm invariant for a monitor to
-            # check — degrade the injected drift to the NaN form the
-            # row screens catch (same rule as expectation_sweep)
-            poison = "nan"
-        B = pm.shape[0]
-        # reverse mode holds primal + cotangent planes: the memory wall
-        # prices at 2x the forward sweep's working set
-        pol = self._batch_policy(B, mem_factor=2.0)
-        mode = pol["mode"]
-        pm_run, B = self._padded_params(pm, mode)
-        pm_run = self._place_batch(pm_run, mode)
-        fn = self._grad_fn(mode, tier)
-        if state_f is None:
-            state_f = jnp.zeros((2, 1 << n),
-                                dtype=self.env.precision.real_dtype
-                                ).at[0, 0].set(1.0)
-        elif getattr(state_f, "shape", None) != (2, 1 << n):
-            raise ValueError(
-                f"value_and_grad_sweep state_f must be shared "
-                f"(2, {1 << n}) planes; got "
-                f"{getattr(state_f, 'shape', None)}")
-        args = (state_f, pm_run, jnp.asarray(xm), jnp.asarray(ym),
-                jnp.asarray(zm),
-                jnp.asarray(coeffs, dtype=self.env.precision.real_dtype))
-        ann_name = (f"quest_tpu.circuits.grad_sweep:"
-                    f"b{pm_run.shape[0]}:t{T}:"
-                    f"{tier.name if tier is not None else 'env'}")
-        aot = self._aot_lookup(self._warm_form_key("grad", mode, tier),
-                               args)
+        with dispatch_annotation("quest_tpu.circuits.prepare"):
+            tier = self._grad_tier(tier)
+            nparams = len(self.param_names)
+            if nparams == 0:
+                raise ValueError(
+                    "this circuit declares no parameters; there is nothing "
+                    "to differentiate (record angles via "
+                    "Circuit.parameter / Param placeholders)")
+            nq, T, xm, ym, zm, coeffs = self._pauli_operands(hamiltonian)
+            n = self.num_qubits
+            pm = self._validated_param_matrix(param_matrix)
+            sp = _profile.profile_dispatch("circuits.grad_sweep")
+            poison = _faults.fire("circuits.grad_sweep")
+            if poison == "precision":
+                # gradients carry no unit-norm invariant for a monitor to
+                # check — degrade the injected drift to the NaN form the
+                # row screens catch (same rule as expectation_sweep)
+                poison = "nan"
+            B = pm.shape[0]
+            # reverse mode holds primal + cotangent planes: the memory wall
+            # prices at 2x the forward sweep's working set
+            pol = self._batch_policy(B, mem_factor=2.0)
+            mode = pol["mode"]
+            pm_run, B = self._padded_params(pm, mode)
+            pm_run = self._place_batch(pm_run, mode)
+            fn = self._grad_fn(mode, tier)
+            if state_f is None:
+                state_f = zero_state(n, self.env.precision.real_dtype)
+            elif getattr(state_f, "shape", None) != (2, 1 << n):
+                raise ValueError(
+                    f"value_and_grad_sweep state_f must be shared "
+                    f"(2, {1 << n}) planes; got "
+                    f"{getattr(state_f, 'shape', None)}")
+            args = (state_f, pm_run, jnp.asarray(xm), jnp.asarray(ym),
+                    jnp.asarray(zm),
+                    jnp.asarray(coeffs, dtype=self.env.precision.real_dtype))
+            ann_name = (f"quest_tpu.circuits.grad_sweep:"
+                        f"b{pm_run.shape[0]}:t{T}:"
+                        f"{tier.name if tier is not None else 'env'}")
+            aot = self._aot_lookup(self._warm_form_key("grad", mode, tier),
+                                   args)
         out = None
         if aot is not None:
             try:

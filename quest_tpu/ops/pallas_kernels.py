@@ -720,17 +720,18 @@ def apply_layer(state: jnp.ndarray, num_qubits: int, layer: LayerOp,
     mat_spec = pl.BlockSpec(mstack.shape, lambda i: (0, 0, 0))
     tab_spec = pl.BlockSpec(tstack.shape, lambda i: (0, 0))
     xmat_spec = pl.BlockSpec(xstack.shape, lambda i: (0, 0, 0))
-    with jax.named_scope(f"pallas_layer_{layer.members}gates"):
-        out_re, out_im = pl.pallas_call(
-            kernel,
-            grid=(total_rows // block_rows,),
-            in_specs=[state_spec, state_spec, mat_spec, mat_spec,
-                      tab_spec, tab_spec, xmat_spec, xmat_spec],
-            out_specs=[state_spec, state_spec],
-            out_shape=[jax.ShapeDtypeStruct((total_rows, 128), rdtype)] * 2,
-            interpret=interpret,
-            **_compiler_kwargs(interpret, vmem_limit),
-        )(re, im, mre, mim, tre, tim, xre, xim)
+    # the kernel's name is the op name a device trace shows
+    out_re, out_im = pl.pallas_call(
+        kernel,
+        name=f"pallas_layer_{layer.members}gates",
+        grid=(total_rows // block_rows,),
+        in_specs=[state_spec, state_spec, mat_spec, mat_spec,
+                  tab_spec, tab_spec, xmat_spec, xmat_spec],
+        out_specs=[state_spec, state_spec],
+        out_shape=[jax.ShapeDtypeStruct((total_rows, 128), rdtype)] * 2,
+        interpret=interpret,
+        **_compiler_kwargs(interpret, vmem_limit),
+    )(re, im, mre, mim, tre, tim, xre, xim)
     return jax.lax.complex(out_re, out_im).reshape(-1).astype(state.dtype)
 
 
@@ -764,19 +765,18 @@ def apply_layer_batched(states: jnp.ndarray, num_qubits: int, layer: LayerOp,
     mat_spec = pl.BlockSpec(mstack.shape, lambda b, i: (0, 0, 0))
     tab_spec = pl.BlockSpec(tstack.shape, lambda b, i: (0, 0))
     xmat_spec = pl.BlockSpec(xstack.shape, lambda b, i: (0, 0, 0))
-    with jax.named_scope(
-            f"pallas_layer_b{batch}_{layer.members}gates"):
-        out_re, out_im = pl.pallas_call(
-            kernel,
-            grid=(batch, total_rows // block_rows),
-            in_specs=[state_spec, state_spec, mat_spec, mat_spec,
-                      tab_spec, tab_spec, xmat_spec, xmat_spec],
-            out_specs=[state_spec, state_spec],
-            out_shape=[jax.ShapeDtypeStruct((batch, total_rows, 128),
-                                            rdtype)] * 2,
-            interpret=interpret,
-            **_compiler_kwargs(interpret, vmem_limit),
-        )(re, im, mre, mim, tre, tim, xre, xim)
+    out_re, out_im = pl.pallas_call(
+        kernel,
+        name=f"pallas_layer_b{batch}_{layer.members}gates",
+        grid=(batch, total_rows // block_rows),
+        in_specs=[state_spec, state_spec, mat_spec, mat_spec,
+                  tab_spec, tab_spec, xmat_spec, xmat_spec],
+        out_specs=[state_spec, state_spec],
+        out_shape=[jax.ShapeDtypeStruct((batch, total_rows, 128),
+                                        rdtype)] * 2,
+        interpret=interpret,
+        **_compiler_kwargs(interpret, vmem_limit),
+    )(re, im, mre, mim, tre, tim, xre, xim)
     return jax.lax.complex(out_re, out_im).reshape(batch, -1).astype(
         states.dtype)
 
@@ -862,6 +862,7 @@ def apply_mxu_tile(state: jnp.ndarray, num_qubits: int, u: np.ndarray,
             zt = jnp.zeros((1, 1), rdtype)
             return pl.pallas_call(
                 kernel,
+                name=f"pallas_mxu_tile_{dim}",
                 grid=(total_rows // block_rows,),
                 in_specs=[state_spec, state_spec, dummy_spec, dummy_spec,
                           tab_spec, tab_spec, xmat_spec, xmat_spec],
@@ -881,8 +882,7 @@ def apply_mxu_tile(state: jnp.ndarray, num_qubits: int, u: np.ndarray,
     im = jnp.imag(state).astype(rdtype).reshape(total_rows, 128)
     xre = jnp.asarray(m.real, rdtype)[None]
     xim = jnp.asarray(m.imag, rdtype)[None]
-    with jax.named_scope(f"pallas_mxu_tile_{dim}"):
-        out_re, out_im = call(re, im, xre, xim)
+    out_re, out_im = call(re, im, xre, xim)
     return jax.lax.complex(out_re, out_im).reshape(-1).astype(state.dtype)
 
 
@@ -984,18 +984,18 @@ def fused_kraus_apply_batched(states: jnp.ndarray, num_qubits: int,
     else:
         from jax.experimental.pallas import tpu as pltpu
         p_spec = u_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    with jax.named_scope(f"pallas_kraus_t{T}_k{K}"):
-        out_re, out_im = pl.pallas_call(
-            kernel,
-            grid=(T, total_rows // block_rows),
-            in_specs=[state_spec, state_spec, k_spec, k_spec, p_spec,
-                      u_spec],
-            out_specs=[state_spec, state_spec],
-            out_shape=[jax.ShapeDtypeStruct((T, total_rows, 128),
-                                            rdtype)] * 2,
-            interpret=interpret,
-            **_compiler_kwargs(interpret, vmem_limit),
-        )(re, im, kre, kim, p2, u2)
+    out_re, out_im = pl.pallas_call(
+        kernel,
+        name=f"pallas_kraus_t{T}_k{K}",
+        grid=(T, total_rows // block_rows),
+        in_specs=[state_spec, state_spec, k_spec, k_spec, p_spec,
+                  u_spec],
+        out_specs=[state_spec, state_spec],
+        out_shape=[jax.ShapeDtypeStruct((T, total_rows, 128),
+                                        rdtype)] * 2,
+        interpret=interpret,
+        **_compiler_kwargs(interpret, vmem_limit),
+    )(re, im, kre, kim, p2, u2)
     return jax.lax.complex(out_re, out_im).reshape(T, -1).astype(
         states.dtype)
 

@@ -52,6 +52,8 @@ traffic opens).
 from __future__ import annotations
 
 import collections
+import functools
+import itertools
 import queue
 import threading
 import time
@@ -59,6 +61,7 @@ import weakref
 from concurrent.futures import Future
 from typing import Optional, Sequence
 
+import jax
 import numpy as np
 
 from ..circuits import Circuit, CompiledCircuit, _BoundedExecutableCache
@@ -77,7 +80,7 @@ from .coalesce import (KIND_EVOLVE, KIND_EXPECTATION, KIND_GRADIENT,
                        KIND_GROUND, KIND_SAMPLE, KIND_STATE,
                        KIND_TRAJECTORY, CoalescePolicy,
                        coalesce_key, split_ready)
-from .metrics import ServiceMetrics
+from .metrics import PHASES, ServiceMetrics
 from .sched import DEFAULT_TENANT, TenantPolicy, WFQScheduler
 
 __all__ = ["ServeError", "QueueFull", "DeadlineExceeded", "ServiceClosed",
@@ -93,10 +96,11 @@ class _Inflight:
     materialize, screen, and fan the batch out."""
 
     __slots__ = ("batch", "pkey", "cc", "tier", "B", "padded", "kind",
-                 "t_dispatch", "traced", "poison", "guard", "sp", "raw")
+                 "t_dispatch", "traced", "poison", "guard", "sp", "raw",
+                 "seq")
 
     def __init__(self, batch, cc, tier, B, padded, kind, t_dispatch,
-                 traced, poison, guard, sp, raw):
+                 traced, poison, guard, sp, raw, seq):
         self.batch = batch
         self.pkey = ""
         self.cc = cc
@@ -110,6 +114,7 @@ class _Inflight:
         self.guard = guard
         self.sp = sp
         self.raw = raw
+        self.seq = seq
 
 
 class ServeError(RuntimeError):
@@ -401,6 +406,17 @@ class SimulationService:
             self.name, self._registry_stats, kind="service", owner=self)
         self._heartbeat = time.monotonic()
         self._stall_flagged = False
+        # dispatch-loop phase spans: a per-service dispatch sequence
+        # number joins the device trace's phase spans to the request
+        # traces' dispatch spans; each phase adds to its exact sum in
+        # ServiceMetrics, the dispatcher's idle counting from the first
+        # submitted request on (set-up and warm() are not serving)
+        self._seqs = itertools.count(1)
+        self._last_seq = 0
+        self._first_submit_t: Optional[float] = None
+        self._phase_acc = {p: functools.partial(self.metrics.add_phase_s,
+                                                p) for p in PHASES}
+        self._phase_acc["wait"] = self._add_wait
         self._watchdog_stop = threading.Event()
         self._watchdog: Optional[threading.Thread] = None
         if self.pipeline_depth > 1:
@@ -841,6 +857,8 @@ class SimulationService:
                 self._backlog += 1
                 self._note_queued(req, 1)
                 self._queue.append(req)
+                if self._first_submit_t is None:
+                    self._first_submit_t = time.perf_counter()
                 self._cond.notify_all()
         except ServeError as e:
             # admission rejected: the future will never resolve, so a
@@ -1262,8 +1280,8 @@ class SimulationService:
                                  dict(self._tenant_inflight)},
                "resilience": res,
                "telemetry": self.tracer.stats(),
-               # the model-vs-measured layer: per-key device-time
-               # percentiles + roofline_frac and the drift gauges (the
+               # the model-vs-measured layer: per-key wall-to-ready
+               # percentiles + achieved bytes/s and the drift gauges (the
                # profiler is process-global; tools/obs_console.py's
                # profiler panel reads this section)
                "profile": _profile.profiler().snapshot()}
@@ -1351,6 +1369,24 @@ class SimulationService:
         return compiled.env.num_devices if compiled.env.mesh is not None \
             else 1
 
+    def _phase(self, phase: str, seq: int, live: int, bucket: int = 0):
+        """The span of one dispatcher phase (``serve/metrics.PHASES``):
+        a host event ``quest_tpu.serve.<phase>`` on the device trace's
+        clock, carrying the dispatch's ``seq``, its live requests and
+        its bucket (0 before a batch exists), that adds its seconds to
+        the phase's sum in :attr:`metrics`."""
+        return dispatch_annotation(f"quest_tpu.serve.{phase}",
+                                   self._phase_acc[phase], seq=seq,
+                                   live=live, bucket=bucket)
+
+    def _add_wait(self, seconds: float) -> None:
+        """The dispatcher's idle, counted from the first submitted
+        request on: a wait that began before it is clipped to it."""
+        t0 = self._first_submit_t
+        if t0 is not None:
+            self.metrics.add_phase_s(
+                "wait", min(seconds, time.perf_counter() - t0))
+
     def _idle_wait(self) -> float:
         """The longest the dispatcher may sleep with no scheduled wake
         deadline. Precise waking (submit/pause/resume/close all notify
@@ -1399,7 +1435,9 @@ class SimulationService:
                     # (deadlines keep counting; they expire on resume —
                     # resume()/close() notify, so the wait only bounds
                     # the heartbeat cadence)
-                    self._cond.wait(timeout=self._idle_wait())
+                    with self._phase("wait", self._last_seq + 1,
+                                     self._waiting(pending)):
+                        self._cond.wait(timeout=self._idle_wait())
                     continue
                 if self._closed and not self._drain_on_close:
                     for req in list(self._queue) + \
@@ -1411,54 +1449,18 @@ class SimulationService:
                                 "service closed before dispatch"))
                     self._queue.clear()
                     return
-                while self._queue:
-                    req = self._queue.popleft()
-                    pending.setdefault(req.key, []).append(req)
-                if not pending:
+                if not self._queue and not pending:
                     if self._closed:
                         return
                     # nothing admitted anywhere: sleep until notified
                     # (submit notifies) — no deadline can pass while
                     # nothing is pending
-                    self._cond.wait(timeout=self._idle_wait())
+                    with self._phase("wait", self._last_seq + 1, 0):
+                        self._cond.wait(timeout=self._idle_wait())
                     continue
-            now = time.monotonic()
-            self._expire(pending, now)
-            drain = self._closed
-            ready: list = []
-            next_deadline = None
-            for key in list(pending):
-                group = pending[key]
-                if drain:
-                    # shutdown flushes everything — a retry backoff must
-                    # not outlive the service
-                    eligible, held = group, []
-                else:
-                    # retry backoff: requests sleeping out their delay
-                    # stay pending (invisible to max-wait maturity) and
-                    # wake the loop when the earliest delay lapses
-                    eligible = [r for r in group if r.not_before <= now]
-                    held = [r for r in group if r.not_before > now]
-                batches, rest, nd = split_ready(eligible, now,
-                                                self.policy, drain=drain)
-                rest = rest + held
-                if held:
-                    wake = min(r.not_before for r in held)
-                    nd = wake if nd is None else min(nd, wake)
-                if rest:
-                    pending[key] = rest
-                else:
-                    del pending[key]
-                if rest:
-                    # a surviving request's expiry is a wake deadline
-                    # too: precise waking must run _expire on time, not
-                    # an arbitrary 50 ms later
-                    exp = min(r.deadline for r in rest)
-                    nd = exp if nd is None else min(nd, exp)
-                ready.extend(batches)
-                if nd is not None:
-                    next_deadline = nd if next_deadline is None \
-                        else min(next_deadline, nd)
+            with self._phase("coalesce", self._last_seq + 1,
+                             self._waiting(pending)):
+                ready, next_deadline, drain = self._coalesce(pending)
             if not ready:
                 with self._cond:
                     if not self._queue and not self._closed:
@@ -1471,14 +1473,10 @@ class SimulationService:
                             else max(1e-5, min(
                                 next_deadline - time.monotonic(),
                                 self._idle_wait()))
-                        self._cond.wait(timeout=wait)
+                        with self._phase("wait", self._last_seq + 1,
+                                         self._waiting(pending)):
+                            self._cond.wait(timeout=wait)
                 continue
-            if self.scheduler == "wfq" and len(ready) > 1:
-                # weighted-fair dispatch order: strict priority class,
-                # then virtual finish tags over projected mesh seconds
-                entries = [(b[0].tenant, self._batch_cost(b), b)
-                           for b in ready]
-                ready = [b for _, _, b in self._sched.order(entries)]
             dispatched = 0
             deferred: list = []
             for batch in ready:
@@ -1510,7 +1508,68 @@ class SimulationService:
                 # notifies) instead of spinning on mature batches
                 with self._cond:
                     if not self._queue and not self._closed:
-                        self._cond.wait(timeout=self._idle_wait())
+                        with self._phase("wait", self._last_seq + 1,
+                                         self._waiting(pending)):
+                            self._cond.wait(timeout=self._idle_wait())
+
+    def _waiting(self, pending: dict) -> int:
+        """Requests admitted but not yet dispatched (queued or
+        pending), as a span's ``live``."""
+        return len(self._queue) + sum(len(g) for g in pending.values())
+
+    def _coalesce(self, pending: dict):
+        """Drain the admission queue into ``pending`` (coalesce key ->
+        FIFO list), expire stale requests, split out the mature batches
+        and order them. Returns ``(ready batches, next wake deadline or
+        None, drain)``."""
+        with self._cond:
+            while self._queue:
+                req = self._queue.popleft()
+                pending.setdefault(req.key, []).append(req)
+        now = time.monotonic()
+        self._expire(pending, now)
+        drain = self._closed
+        ready: list = []
+        next_deadline = None
+        for key in list(pending):
+            group = pending[key]
+            if drain:
+                # shutdown flushes everything — a retry backoff must
+                # not outlive the service
+                eligible, held = group, []
+            else:
+                # retry backoff: requests sleeping out their delay
+                # stay pending (invisible to max-wait maturity) and
+                # wake the loop when the earliest delay lapses
+                eligible = [r for r in group if r.not_before <= now]
+                held = [r for r in group if r.not_before > now]
+            batches, rest, nd = split_ready(eligible, now,
+                                            self.policy, drain=drain)
+            rest = rest + held
+            if held:
+                wake = min(r.not_before for r in held)
+                nd = wake if nd is None else min(nd, wake)
+            if rest:
+                pending[key] = rest
+            else:
+                del pending[key]
+            if rest:
+                # a surviving request's expiry is a wake deadline
+                # too: precise waking must run _expire on time, not
+                # an arbitrary 50 ms later
+                exp = min(r.deadline for r in rest)
+                nd = exp if nd is None else min(nd, exp)
+            ready.extend(batches)
+            if nd is not None:
+                next_deadline = nd if next_deadline is None \
+                    else min(next_deadline, nd)
+        if self.scheduler == "wfq" and len(ready) > 1:
+            # weighted-fair dispatch order: strict priority class,
+            # then virtual finish tags over projected mesh seconds
+            entries = [(b[0].tenant, self._batch_cost(b), b)
+                       for b in ready]
+            ready = [b for _, _, b in self._sched.order(entries)]
+        return ready, next_deadline, drain
 
     def _expire(self, pending: dict, now: float) -> None:
         for key in list(pending):
@@ -1772,7 +1831,7 @@ class SimulationService:
         retry/fail each request per the policy."""
         self._heartbeat = time.monotonic()
         try:
-            results, bad_rows, viol_rows, t_dispatch, padded = \
+            results, bad_rows, viol_rows, t_dispatch, padded, seq = \
                 self._dispatch_batch(batch)
         # quest: allow-broad-except(THE classified fault barrier:
         # classify() routes FATAL/TRANSIENT/POISON/PRECISION to typed
@@ -1786,7 +1845,7 @@ class SimulationService:
         self._breaker.record_success(pkey)
         self._consec_faults.pop(pkey, None)
         self._fan_out(batch, results, bad_rows, viol_rows, t_dispatch,
-                      padded)
+                      padded, seq)
 
     def _recover_group(self, batch: list, pkey: str, depth: int,
                        e: BaseException) -> None:
@@ -1883,8 +1942,8 @@ class SimulationService:
     def _dispatch_batch(self, batch: list):
         """One synchronous engine dispatch for one group (the
         ``pipeline_depth=1`` path): issue and complete back-to-back.
-        Returns ``(results, bad_rows, viol_rows, t_dispatch, padded)``
-        where ``bad_rows`` indexes result rows screened out as
+        Returns ``(results, bad_rows, viol_rows, t_dispatch, padded,
+        seq)`` where ``bad_rows`` indexes result rows screened out as
         non-finite (NaN poisoning — those requests get a typed failure;
         their batchmates are unaffected) and ``viol_rows`` indexes
         FINITE rows whose norm/trace drifts past the batch tier's
@@ -1899,19 +1958,31 @@ class SimulationService:
         the dispatcher's coalescing of the NEXT batch with this one's
         device compute. No host-side materialization happens here —
         block-until-ready, screening, and span close all live in
-        :meth:`_complete_batch`."""
+        :meth:`_complete_batch`. The whole issue, parameter packing and
+        the program's own preparation included, is the dispatch's
+        ``issue`` phase."""
         cc = batch[0].compiled
-        tier = batch[0].tier
-        B = len(batch)
-        kind = batch[0].kind
         # trajectory groups (value AND gradient) pad only to the
         # power-of-two bucket — the device multiple lives on the
         # (inner) trajectory axis, and a padded REQUEST row costs a
         # whole throwaway ensemble
         padded = self.policy.bucket_size(
-            B, 1 if (kind == KIND_TRAJECTORY
-                     or isinstance(cc, TrajectoryProgram))
+            len(batch), 1 if (batch[0].kind == KIND_TRAJECTORY
+                              or isinstance(cc, TrajectoryProgram))
             else self._device_multiple(cc))
+        seq = next(self._seqs)
+        self._last_seq = seq
+        with self._phase("issue", seq, len(batch), padded):
+            return self._launch_batch(batch, padded, seq)
+
+    def _launch_batch(self, batch: list, padded: int,
+                      seq: int) -> _Inflight:
+        """:meth:`_issue_batch`'s work: pack the parameters, open the
+        sampled requests' dispatch spans and launch the executable."""
+        cc = batch[0].compiled
+        tier = batch[0].tier
+        B = len(batch)
+        kind = batch[0].kind
         pm = np.zeros((padded, len(cc.param_names)), dtype=np.float64)
         for i, req in enumerate(batch):
             pm[i] = req.param_vec
@@ -1930,7 +2001,7 @@ class SimulationService:
                     kind=kind, tier=tier_name)
             req.dspan = ctx.begin("dispatch", batch=B, bucket=padded,
                                   kind=kind, tier=tier_name,
-                                  service=self.name)
+                                  service=self.name, seq=seq)
         if tier is not None and tier.name == "fast":
             self.metrics.incr("fast_tier_dispatches")
         sp = None
@@ -2031,24 +2102,37 @@ class SimulationService:
         # exception always propagates to the classified barrier)
         except BaseException as e:
             inf = _Inflight(batch, cc, tier, B, padded, kind,
-                            t_dispatch, traced, poison, guard, sp, None)
+                            t_dispatch, traced, poison, guard, sp, None,
+                            seq)
             self._close_dspans(inf, status=type(e).__name__)
             raise
         return _Inflight(batch, cc, tier, B, padded, kind, t_dispatch,
-                         traced, poison, guard, sp, raw)
+                         traced, poison, guard, sp, raw, seq)
 
     def _complete_batch(self, inf: _Inflight):
         """Materialize one issued batch (THE block-until-ready point —
         the completion thread's whole job in pipelined mode), run the
         per-row health screens and the fidelity monitor, price the
-        dispatch, and close its spans. Returns ``(results, bad_rows,
-        viol_rows, t_dispatch, padded)``."""
+        dispatch, and close its spans: the dispatch's ``complete``
+        phase, holding its ``ready`` phase (the wait on the device).
+        Returns ``(results, bad_rows, viol_rows, t_dispatch, padded,
+        seq)``."""
+        with self._phase("complete", inf.seq, inf.B, inf.padded):
+            return self._materialize_batch(inf)
+
+    def _materialize_batch(self, inf: _Inflight):
+        """:meth:`_complete_batch`'s work."""
         batch, cc, tier = inf.batch, inf.cc, inf.tier
         B, padded, kind = inf.B, inf.padded, inf.kind
         poison, guard, sp = inf.poison, inf.guard, inf.sp
         viol = ()
         norms = None
         try:
+            with self._phase("ready", inf.seq, B, padded):
+                # quest: allow-host-sync(THE block-until-ready point:
+                # the dispatcher waits here for the device, and nowhere
+                # else)
+                jax.block_until_ready(inf.raw)
             if kind == KIND_TRAJECTORY:
                 means, errs, info = inf.raw
                 means = _faults.poison_output(poison,
@@ -2203,7 +2287,7 @@ class SimulationService:
             raise
         self._close_dspans(inf)
         return (results, {int(r) for r in bad}, {int(r) for r in viol},
-                inf.t_dispatch, padded)
+                inf.t_dispatch, padded, inf.seq)
 
     def _close_dspans(self, inf: _Inflight,
                       status: Optional[str] = None) -> None:
@@ -2328,7 +2412,19 @@ class SimulationService:
             self._cond.notify_all()
 
     def _fan_out(self, batch: list, results: list, bad_rows: set,
-                 viol_rows: set, t_dispatch: float, padded: int) -> None:
+                 viol_rows: set, t_dispatch: float, padded: int,
+                 seq: int) -> None:
+        """Account one completed dispatch and resolve its futures (the
+        caller's done-callbacks run here): the dispatch's ``fan_out``
+        phase."""
+        with self._phase("fan_out", seq, len(batch), padded):
+            self._resolve_batch(batch, results, bad_rows, viol_rows,
+                                t_dispatch, padded)
+
+    def _resolve_batch(self, batch: list, results: list, bad_rows: set,
+                       viol_rows: set, t_dispatch: float,
+                       padded: int) -> None:
+        """:meth:`_fan_out`'s work."""
         cc = batch[0].compiled
         B = len(batch)
         self._last_cc = cc

@@ -28,7 +28,7 @@ import threading
 
 from ..telemetry.metrics import Counter, Histogram
 
-__all__ = ["ServiceMetrics", "RouterMetrics", "WireMetrics"]
+__all__ = ["ServiceMetrics", "RouterMetrics", "WireMetrics", "PHASES"]
 
 
 _COUNTERS = (
@@ -85,6 +85,13 @@ _COUNTERS = (
     "ground_converged",       # ground handles that met their residual tol
 )
 
+# the dispatcher's phases, each timed by its span (serve/engine.py):
+# ``wait`` idle on the condition, ``coalesce`` queue drain to ready
+# batches, ``issue`` packing + launch, ``complete`` materialisation +
+# screens + pricing (holding ``ready``, the blocking wait on the
+# device), ``fan_out`` resolving the futures
+PHASES = ("wait", "coalesce", "issue", "ready", "complete", "fan_out")
+
 # per-tenant counter family (a subset of the service counters that is
 # meaningful per submitting tenant; tracked by incr_tenant)
 _TENANT_COUNTERS = ("submitted", "completed", "rejected_quota",
@@ -115,6 +122,7 @@ class ServiceMetrics:
         self._c = {name: Counter(name, lock=self._lock)
                    for name in _COUNTERS}
         self._max_occupancy = 0
+        self._phase_s = dict.fromkeys(PHASES, 0.0)
         self.queue_depth_fn = None
         # per-tenant accounting (ISSUE 16): created lazily on first
         # touch so single-tenant services pay nothing new; all three
@@ -148,6 +156,11 @@ class ServiceMetrics:
                 self._c["shared_batch_requests"].inc(size)
             self._c["padded_rows"].inc(max(0, padded_size - size))
             self._max_occupancy = max(self._max_occupancy, size)
+
+    def add_phase_s(self, phase: str, seconds: float) -> None:
+        """Add one span's seconds to a dispatcher phase's exact sum."""
+        with self._lock:
+            self._phase_s[phase] += seconds
 
     def record_latency(self, total_s: float, queue_wait_s: float) -> None:
         self._latency.observe(total_s)
@@ -243,11 +256,19 @@ class ServiceMetrics:
         with at least one other request. Percentiles are estimated from
         the fixed-bucket histograms (interpolated inside the owning
         bucket, clamped to the observed max).
+
+        The dispatcher's time, as exact sums of its phase spans:
+        ``dispatch_wait_s`` waiting on the device (``ready``),
+        ``dispatch_host_s`` its own host work (``coalesce + issue +
+        complete - ready + fan_out``), ``dispatcher_idle_s`` waiting for
+        work (``wait``, from the first submitted request on), and
+        ``host_phase_s`` each phase's sum.
         """
         with self._lock:
             # atomic family read (the RLock is the counters' own lock)
             c = {name: cnt.value for name, cnt in self._c.items()}
             max_occ = self._max_occupancy
+            ph = dict(self._phase_s)
         batches = c["batches"]
         dispatched = c["coalesced_requests"]
         depth = 0
@@ -271,6 +292,11 @@ class ServiceMetrics:
             "p99_latency_s": self._latency.percentile(99.0),
             "p50_queue_wait_s": self._queue_wait.percentile(50.0),
             "p99_queue_wait_s": self._queue_wait.percentile(99.0),
+            "dispatch_wait_s": ph["ready"],
+            "dispatch_host_s": ph["coalesce"] + ph["issue"]
+            + ph["complete"] - ph["ready"] + ph["fan_out"],
+            "dispatcher_idle_s": ph["wait"],
+            "host_phase_s": ph,
             # nested per-tenant block: the Prometheus exporter flattens
             # numeric leaves, so each tenant's counters/percentiles
             # export as tenants_<name>_<metric> series automatically

@@ -224,8 +224,6 @@ class PerfLedger:
                 doc["mean_s"] = doc["total_s"] / doc["count"]
                 doc["bytes_per_pass"] = float(
                     key.get("bytes_per_pass", 0.0))
-                doc["roofline_frac"] = float(
-                    key.get("roofline_frac", 0.0))
                 doc["updated_wall"] = round(time.time(), 3)
                 if self._write(path, doc):
                     written += 1

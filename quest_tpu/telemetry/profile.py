@@ -15,11 +15,12 @@ did. This module is that loop:
   **wall-to-ready** at the same boundaries QL004's fault hooks and
   trace annotations cover, keyed by ``(site, program digest, kind,
   batch bucket, tier, dtype, sharding mode, replica)`` into fixed-
-  bucket histograms. Because every site passes the planner's known
-  bytes-per-pass, each key derives a live achieved-bytes/s and
-  ``roofline_frac`` — every mode (per-gate, fused, batched sweep,
-  trajectory wave, sharded) gets a roofline number, not just
-  ``bench.py``'s offline one.
+  bucket histograms. Every site passes the planner's known
+  bytes-per-pass, so each key also reports planner bytes over host
+  wall-to-ready seconds (``achieved_bytes_per_s``). That is host time,
+  not device time, and it is no share of a roofline: after fusion the
+  planner's bytes can exceed what the device moved. A device roofline
+  comes from a profiler trace (``benchmark/trace_reduce.py``).
 - :class:`DriftMonitor` — compares modeled vs measured wherever a model
   exists (``comm_plan``: the plan's modeled collective seconds vs the
   measured collective-bearing dispatch time; ``batch_amp_comm``: the
@@ -74,9 +75,9 @@ __all__ = ["DEFAULT_PROFILE_RATE", "DispatchProfiler", "DriftMonitor",
 # modeled overhead well under the 1% bench budget on every backend.
 DEFAULT_PROFILE_RATE = 0.125
 
-# Peak memory bandwidth (B/s) per ``device_kind`` as JAX reports it, for
-# roofline_frac — the one table bench.py's rows use too. A device that is
-# missing here is an error, never a default.
+# Peak memory bandwidth (B/s) per ``device_kind`` as JAX reports it: the
+# one table bench.py's rows and the layout planner's memory model use. A
+# device that is missing here is an error, never a default.
 PEAK_BYTES_PER_S = {
     # Google Cloud documentation, "TPU v5e": 819 GB/s of HBM per chip
     "TPU v5 lite": 8.19e11,
@@ -89,12 +90,8 @@ PEAK_BYTES_PER_S = {
 
 
 def platform_peak_bytes_per_s() -> tuple:
-    """``(device_kind, peak B/s)`` for the current backend's device —
-    ``QUEST_TPU_PEAK_BW`` (B/s) overrides the table. Raises ``KeyError``
-    for a device kind the table does not hold."""
-    env = os.environ.get("QUEST_TPU_PEAK_BW", "").strip()
-    if env:
-        return ("env-override", float(env))
+    """``(device_kind, peak B/s)`` for the current backend's device.
+    Raises ``KeyError`` for a device kind the table does not hold."""
     import jax
     kind = jax.devices()[0].device_kind
     if kind not in PEAK_BYTES_PER_S:
@@ -292,7 +289,6 @@ class DispatchProfiler:
         self._keys: dict = {}
         self.drift = DriftMonitor(threshold_log2=drift_threshold_log2,
                                   baseline_n=drift_baseline_n)
-        self._peak = None       # (name, B/s), resolved lazily
         metrics_registry().register(name, self.snapshot,
                                     kind="profiler", owner=self)
 
@@ -343,14 +339,9 @@ class DispatchProfiler:
 
     # -- reading -----------------------------------------------------------
 
-    def _peak_bw(self) -> tuple:
-        if self._peak is None:
-            self._peak = platform_peak_bytes_per_s()
-        return self._peak
-
     @staticmethod
-    def _render_keys(items, peak_bw: float) -> dict:
-        """Per-key percentile/roofline documents from ``(keystr,
+    def _render_keys(items) -> dict:
+        """Per-key percentile documents from ``(keystr,
         _KeyStats)`` pairs — shared by :meth:`snapshot` (live view) and
         :meth:`flush_to_ledger` (drained view)."""
         keys = {}
@@ -368,24 +359,20 @@ class DispatchProfiler:
                 "p99_s": ks.hist.percentile(99.0),
                 "bytes_per_pass": ks.bytes_per_pass,
                 "achieved_bytes_per_s": achieved,
-                "roofline_frac": achieved / peak_bw if peak_bw else 0.0,
             }
         return keys
 
     def snapshot(self) -> dict:
         """The profiler's full state as a plain dict: counters, per-key
-        device-time percentiles + achieved bytes/s + roofline_frac, and
+        host wall-to-ready percentiles + achieved bytes/s, and
         the drift monitor's per-model gauges/events."""
-        peak_name, peak_bw = self._peak_bw()
         with self._lock:
             items = list(self._keys.items())
             out = {"sample_rate": self.sample_rate,
                    "dispatches_seen": self._seen,
                    "dispatches_sampled": self._sampled,
-                   "keys_dropped": self._keys_dropped,
-                   "roofline_model": peak_name,
-                   "peak_bytes_per_s": peak_bw}
-        out["keys"] = self._render_keys(items, peak_bw)
+                   "keys_dropped": self._keys_dropped}
+        out["keys"] = self._render_keys(items)
         out["drift"] = self.drift.snapshot()
         return out
 
@@ -413,9 +400,8 @@ class DispatchProfiler:
             self._keys = {}
         if not drained:
             return 0
-        _, peak_bw = self._peak_bw()
         return ledger.record_profile(
-            {"keys": self._render_keys(list(drained.items()), peak_bw)})
+            {"keys": self._render_keys(list(drained.items()))})
 
 
 # ---------------------------------------------------------------------------

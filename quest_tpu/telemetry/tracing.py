@@ -27,9 +27,11 @@ Design constraints, in order:
    trace events (``ph: "X"`` complete events / ``ph: "i"`` instants)
    that load directly in ``ui.perfetto.dev`` or ``chrome://tracing``.
 4. **Device alignment.** :func:`dispatch_annotation` wraps every engine
-   dispatch in a ``jax.profiler.TraceAnnotation`` so a device profile
-   captured with :func:`quest_tpu.profiling.trace` shows the same
-   dispatch names the host spans carry.
+   dispatch, and each phase of the service's dispatch loop, in a
+   ``jax.profiler.TraceAnnotation`` so a device profile captured with
+   :func:`quest_tpu.profiling.trace` shows the same dispatch names the
+   host spans carry; a sampled request's ``dispatch`` span and the
+   phase spans share the dispatch's ``seq``.
 """
 
 from __future__ import annotations
@@ -325,16 +327,46 @@ class Tracer:
         return doc
 
 
-def dispatch_annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` for one engine dispatch (the
-    host-side TraceMe is near-free when no profiler session is active),
-    degrading to a null context wherever the profiler API is missing —
-    telemetry must never be the import that breaks a backend."""
+class _TimedSpan:
+    """A profiler span that also adds its elapsed ``perf_counter``
+    seconds to an accumulator when it closes (raising or not)."""
+
+    __slots__ = ("_ann", "_acc", "_t0")
+
+    def __init__(self, ann, acc):
+        self._ann = ann
+        self._acc = acc
+        self._t0 = 0.0
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._acc(time.perf_counter() - self._t0)
+        return self._ann.__exit__(*exc)
+
+
+def dispatch_annotation(name: str, acc=None, **args):
+    """A host span on the device profiler's clock: a
+    ``jax.profiler.TraceAnnotation`` named ``name`` (a fixed
+    ``quest_tpu.``-prefixed string; variable data goes in ``args``,
+    which the profiler records as the event's arguments).
+
+    ``acc``, when given, is called with the span's elapsed
+    ``time.perf_counter()`` seconds as it closes, so one ``with`` block
+    writes the trace event and adds to a counter at the same boundary.
+    With no profiler session active the span costs the TraceMe check
+    plus, with ``acc``, two clock reads. Degrades to a plain timer (or
+    a null context) wherever the profiler API is missing — telemetry
+    must never be the import that breaks a backend."""
     try:
         import jax
-        return jax.profiler.TraceAnnotation(name)
+        ann = jax.profiler.TraceAnnotation(name, **args)
     # quest: allow-broad-except(telemetry boundary: a missing/broken
     # profiler API degrades to a null context -- telemetry must never
     # be the import that breaks a backend)
     except Exception:
-        return contextlib.nullcontext()
+        ann = contextlib.nullcontext()
+    return ann if acc is None else _TimedSpan(ann, acc)

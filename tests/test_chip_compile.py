@@ -153,6 +153,9 @@ def test_apply_layer_compiles(topo, one_chip):
     compiled = jax.jit(step, donate_argnums=0).lower(
         _planes(28, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # the kernel's name is the op name the device trace reduction
+    # matches (benchmark/metrics/kernel.layer_roofline.py)
+    assert "%pallas_layer_3gates." in compiled.as_text()
     _fits(compiled)
 
 
@@ -168,6 +171,7 @@ def test_apply_layer_batched_compiles(topo, one_chip):
 
     compiled = jax.jit(step).lower(_planes(20, one_chip, batch=4)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert "%pallas_layer_b4_3gates." in compiled.as_text()
     _fits(compiled)
 
 
@@ -179,6 +183,7 @@ def test_apply_mxu_tile_compiles(topo, one_chip):
     compiled = jax.jit(lambda z: pk.apply_mxu_tile(
         z, 28, u, (0, 8))).lower(spec).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert "%pallas_mxu_tile_256." in compiled.as_text()
     _fits(compiled)
 
 
@@ -195,6 +200,7 @@ def test_fused_kraus_apply_batched_compiles(topo, one_chip):
     compiled = jax.jit(lambda s, pr, u: pk.fused_kraus_apply_batched(
         s, n, kstack, pr, u)).lower(states, probs, u01).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert "%pallas_kraus_t4_k2." in compiled.as_text()
     _fits(compiled)
 
 

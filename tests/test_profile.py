@@ -123,8 +123,10 @@ class TestKeys:
         assert key["count"] == 1
         assert key["bytes_per_pass"] > 0.0
         assert key["achieved_bytes_per_s"] > 0.0
-        assert 0.0 < key["roofline_frac"] < 1e3
-        assert snap["peak_bytes_per_s"] > 0.0
+        # planner bytes over host time is no roofline share: the
+        # profiler reports no peak and no fraction of one
+        assert "roofline_frac" not in key
+        assert "peak_bytes_per_s" not in snap
 
     def test_dispatch_stats_profile_section(self, env):
         from quest_tpu.serve import SimulationService
@@ -512,5 +514,5 @@ class TestTools:
         frame = console.render(stats)
         assert "PROFILER" in frame
         assert "circuits.sweep" in frame
-        assert "roofline" in frame
+        assert "p99" in frame
         assert "drift:" in frame

@@ -195,22 +195,21 @@ def _replica_table(replicas: list, indent: str = "  ") -> list:
 
 
 def _profile_lines(prof: dict, indent: str = "  ") -> list:
-    """The profiler panel: per-program device-time percentiles +
-    roofline_frac per key, then the drift-monitor gauges (a
+    """The profiler panel: per-program wall-to-ready percentiles per
+    key, then the drift-monitor gauges (a
     ``dispatch_stats()["profile"]`` section — plain dict, stdlib-only
     rendering)."""
     lines = [indent + _kv((
         ("rate", prof.get("sample_rate", 0.0)),
         ("sampled", f"{prof.get('dispatches_sampled', 0)}"
                     f"/{prof.get('dispatches_seen', 0)}"),
-        ("roofline_model", prof.get("roofline_model")),
     ))]
     keys = prof.get("keys", {}) or {}
     if keys:
         lines.append(
             f"{indent}{'site':<22} {'program':<10} {'kind':<10} "
             f"{'bkt':>4} {'tier':<6} {'shard':<6} {'n':>5} "
-            f"{'p50':>8} {'p99':>8} {'roofline':>8}")
+            f"{'p50':>8} {'p99':>8}")
         ranked = sorted(keys.values(),
                         key=lambda k: -float(k.get("count", 0)))
         for k in ranked[:12]:
@@ -223,8 +222,7 @@ def _profile_lines(prof: dict, indent: str = "  ") -> list:
                 f"{str(k.get('sharding', ''))[:6]:<6} "
                 f"{k.get('count', 0):>5} "
                 f"{_fmt_s(k.get('p50_s')):>8} "
-                f"{_fmt_s(k.get('p99_s')):>8} "
-                f"{k.get('roofline_frac', 0.0):>8.4f}")
+                f"{_fmt_s(k.get('p99_s')):>8}")
         if len(ranked) > 12:
             lines.append(f"{indent}... {len(ranked) - 12} more key(s)")
     drift = (prof.get("drift", {}) or {}).get("models", {}) or {}
