@@ -1839,16 +1839,52 @@ class CompiledCircuit:
         gate_prec = self._gate_prec
         pallas_fast = self._pallas_fast
 
+        # single-chip Pallas routing: besides the layers, every dense
+        # uncontrolled item of at most 4 targets at qubit 10+ runs as a
+        # row-gate pass (ops/pallas_kernels.apply_rowgate_planes)
+        from .ops import pallas_kernels as pk
+        rowgate = [use_layers and not shard_bits and item[0] == "op"
+                   and pk.rowgate_eligible(ops[item[1]].kind, item[3],
+                                           item[2])
+                   for item in plan_items]
+        self._rowgate_passes = sum(rowgate)
+
         def run_plan_seq(state, params):
             """Sequential (single-trace) form: relayouts as plain
             transposes, no collectives (a cross-shard pair-exchange item
             is just the unitary at its physical position here — the
             full-state form reaches any bit). The compiled path on a mesh
-            uses the shard_map program instead; this form serves vmapped
-            uses (sweep), where the BATCH axis is the parallel axis and
-            collectives inside the per-element program cannot be
-            vmapped."""
-            for item in plan_items:
+            uses the shard_map program instead.
+
+            Pallas items (layers and row gates) read and write the
+            state's re/im planes; the planes are kept from one Pallas
+            item to the next and the state turns complex again only
+            before an XLA item."""
+            planes = None
+            for item, as_rowgate in zip(plan_items, rowgate):
+                pallas_item = as_rowgate or (
+                    item[0] == "op" and ops[item[1]].kind == "layer")
+                if pallas_item:
+                    if planes is None:
+                        planes = pk.to_planes(state)
+                    _, i, phys_targets = item[:3]
+                    op = ops[i]
+                    if as_rowgate:
+                        u = op.mat_fn(params) if op.mat_fn is not None \
+                            else op.mat
+                        planes = pk.apply_rowgate_planes(
+                            *planes, n, u, phys_targets,
+                            interpret=self._pallas_interpret)
+                    else:
+                        planes = pk.apply_layer_planes(
+                            *planes, n, op,
+                            interpret=self._pallas_interpret,
+                            fast=pallas_fast)
+                    planes = pass_boundary(planes)
+                    continue
+                if planes is not None:
+                    state = pk.from_planes(*planes).astype(state.dtype)
+                    planes = None
                 if item[0] == "relayout":
                     _, before, after = item
                     state = pass_boundary(
@@ -1856,12 +1892,7 @@ class CompiledCircuit:
                     continue
                 _, i, phys_targets, cmask, fmask, axis_order = item
                 op = ops[i]
-                if op.kind == "layer":
-                    from .ops import pallas_kernels as pk
-                    state = pk.apply_layer(
-                        state, n, op, interpret=self._pallas_interpret,
-                        fast=pallas_fast)
-                elif op.kind == "u":
+                if op.kind == "u":
                     u = op.mat_fn(params) if op.mat_fn is not None \
                         else op.mat
                     state = apply_unitary(state, n, u, phys_targets,
@@ -1873,6 +1904,8 @@ class CompiledCircuit:
                     d = jnp.transpose(jnp.asarray(d), axis_order)
                     state = apply_diagonal(state, n, phys_targets, d)
                 state = pass_boundary(state)
+            if planes is not None:
+                state = pk.from_planes(*planes).astype(state.dtype)
             return state
 
         self._run_plan_seq = run_plan_seq
@@ -2459,18 +2492,21 @@ class CompiledCircuit:
             evolve_steps_fused=bs.get("evolve_steps_fused", 0),
             batched_cache_size=cache_size,
             batched_cache_evictions=cache_evictions,
+            rowgate_passes=self._rowgate_passes,
             precision_tier=self._tier_token(self.tier),
             modeled_tier_error=self._modeled_tier_error())
 
     def _xla_only(self) -> "CompiledCircuit":
-        """This program with the Pallas layer pass off (cached twin).
+        """This program with the Pallas pass off (cached twin).
 
         ``jax.grad`` and ``jax.vmap`` have no rules for a compiled
         ``pallas_call``, so the transform-composable consumers
         (:meth:`expectation_fn`, :meth:`sweep`) trace the twin's
         layer-free plan — identical math, XLA ops only. Execution paths
-        (:meth:`run`, :meth:`apply`) keep the fused kernels."""
-        if not any(getattr(op, "kind", None) == "layer" for op in self._ops):
+        (:meth:`run`, :meth:`apply`) keep the fused kernels and the row
+        gates."""
+        if not self._rowgate_passes and not any(
+                getattr(op, "kind", None) == "layer" for op in self._ops):
             return self
         if getattr(self, "_xla_twin", None) is None:
             self._xla_twin = CompiledCircuit(

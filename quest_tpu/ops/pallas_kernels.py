@@ -47,7 +47,10 @@ DEFAULT_BLOCK_ROWS = 1024
 
 __all__ = ["LANE_QUBITS", "DEFAULT_BLOCK_ROWS", "LayerOp",
            "embed_lane_matrix", "lane_diag_matrix", "lane_diag_vector",
-           "max_mid_qubit", "apply_layer", "apply_layer_batched",
+           "max_mid_qubit", "to_planes", "from_planes", "apply_layer",
+           "apply_layer_planes", "apply_layer_batched",
+           "ROWGATE_MIN_QUBIT", "ROWGATE_MAX_TARGETS", "rowgate_eligible",
+           "apply_rowgate_planes",
            "mxu_group_matrix", "apply_mxu_tile",
            "fused_kraus_apply_batched"]
 
@@ -694,6 +697,21 @@ def _compiler_kwargs(interpret: bool, vmem_limit: int) -> dict:
         vmem_limit_bytes=vmem_limit)}
 
 
+def to_planes(state: jnp.ndarray):
+    """A flat complex state as its ``(rows, 128)`` re/im float planes,
+    the storage the single-state kernels read and write."""
+    rdtype = jnp.float32 if state.dtype == jnp.complex64 else jnp.float64
+    rows = state.shape[0] // 128
+    return (jnp.real(state).astype(rdtype).reshape(rows, 128),
+            jnp.imag(state).astype(rdtype).reshape(rows, 128))
+
+
+def from_planes(re: jnp.ndarray, im: jnp.ndarray) -> jnp.ndarray:
+    """The flat complex state of a plane pair (inverse of
+    :func:`to_planes`)."""
+    return jax.lax.complex(re, im).reshape(-1)
+
+
 def apply_layer(state: jnp.ndarray, num_qubits: int, layer: LayerOp,
                 block_rows: int = DEFAULT_BLOCK_ROWS,
                 interpret: bool = False,
@@ -706,14 +724,24 @@ def apply_layer(state: jnp.ndarray, num_qubits: int, layer: LayerOp,
     compensated f32 accumulation instead of the full-f32 ``HIGHEST``
     passes — the per-tier trade the budget API prices
     (:func:`quest_tpu.profiling.choose_tier`)."""
+    re, im = apply_layer_planes(*to_planes(state), num_qubits, layer,
+                                block_rows=block_rows, interpret=interpret,
+                                fast=fast)
+    return from_planes(re, im).astype(state.dtype)
+
+
+def apply_layer_planes(re: jnp.ndarray, im: jnp.ndarray, num_qubits: int,
+                       layer: LayerOp,
+                       block_rows: int = DEFAULT_BLOCK_ROWS,
+                       interpret: bool = False, fast: bool = False):
+    """:func:`apply_layer` on the ``(rows, 128)`` re/im planes of a state;
+    returns the new plane pair."""
     from jax.experimental import pallas as pl
 
-    rdtype = jnp.float32 if state.dtype == jnp.complex64 else jnp.float64
+    rdtype = re.dtype
     (kstages, mstack, tstack, xstack, mre, mim, tre, tim, xre, xim,
      block_rows, total_rows, vmem_limit) = _layer_operands(
         layer, num_qubits, block_rows, rdtype)
-    re = jnp.real(state).astype(rdtype).reshape(total_rows, 128)
-    im = jnp.imag(state).astype(rdtype).reshape(total_rows, 128)
     kernel = functools.partial(_layer_kernel, stages=tuple(kstages),
                                block_rows=block_rows, fast=fast)
     state_spec = pl.BlockSpec((block_rows, 128), lambda i: (i, 0))
@@ -732,7 +760,198 @@ def apply_layer(state: jnp.ndarray, num_qubits: int, layer: LayerOp,
         interpret=interpret,
         **_compiler_kwargs(interpret, vmem_limit),
     )(re, im, mre, mim, tre, tim, xre, xim)
-    return jax.lax.complex(out_re, out_im).reshape(-1).astype(state.dtype)
+    return out_re, out_im
+
+
+# A row gate's targets are row bits 3 and above (qubit 10 and above), so
+# every partner sub-block of the split view holds whole (8, 128) tiles.
+ROWGATE_MIN_QUBIT = LANE_QUBITS + 3
+ROWGATE_MAX_TARGETS = 4
+
+
+def rowgate_eligible(kind: str, ctrl_mask: int,
+                     targets: Sequence[int]) -> bool:
+    """Whether a planned item runs as a row-gate pass
+    (:func:`apply_rowgate_planes`): a dense uncontrolled operator on 1 to
+    :data:`ROWGATE_MAX_TARGETS` targets, all at qubit
+    :data:`ROWGATE_MIN_QUBIT` or above."""
+    return (kind == "u" and not ctrl_mask
+            and 1 <= len(targets) <= ROWGATE_MAX_TARGETS
+            and min(targets) >= ROWGATE_MIN_QUBIT)
+
+
+def _rowgate_layout(num_qubits: int, targets: tuple, block_rows: int):
+    """The row-gate pass's view and blocks.
+
+    The row index splits at the target bits (``core.apply.split_shape``,
+    descending); each target axis is blocked whole (2), and the other
+    axes, from the lowest up, take what is left of ``block_rows >> k``
+    rows. Returns ``(shape, block, axes)``: the view's shape without the
+    lane axis, the block size per axis (1 = squeezed) and, for each kept
+    axis of the block, ``("t", j)`` for the axis of ``targets[j]``,
+    ``("c", size)`` for a blocked axis above the lowest target, or
+    ``("r", size)`` for the axis below it (second-minor)."""
+    from ..core.apply import split_shape
+
+    k = len(targets)
+    desc = sorted((t - LANE_QUBITS for t in targets), reverse=True)
+    shape = split_shape(num_qubits - LANE_QUBITS, desc)
+    gate_bit = {t - LANE_QUBITS: j for j, t in enumerate(targets)}
+    block = [2] * len(shape)
+    left = max(1, block_rows >> k)
+    for a in range(2 * k, -1, -2):
+        block[a] = min(shape[a], left)
+        left //= block[a]
+    axes = []
+    for a, b in enumerate(block):
+        if a % 2:
+            axes.append(("t", gate_bit[desc[a // 2]]))
+        elif a == 2 * k:
+            axes.append(("r", b))
+        elif b > 1:
+            axes.append(("c", b))
+    return shape, tuple(block), tuple(axes)
+
+
+def _rowgate_kernel(u_ref, re_ref, im_ref, ore_ref, oim_ref, *, axes,
+                    sub, terms):
+    """``out_r = sum_m u[r, m] x_m`` over the ``2^k`` partner sub-blocks
+    of one block, on the VPU, ``sub`` rows of each at a time. ``u_ref``
+    holds ``u``'s real parts, then its imaginary parts, row-major;
+    ``terms[r]`` lists the columns ``m`` that row ``r`` sums. The sums
+    are written with ``lax`` primitives: a dense 4-target operator is
+    about 2,000 of them, and each ``jnp`` operator costs about 1 ms of
+    tracing, which a program's set-up pays for every kernel it traces."""
+    from jax.experimental import pallas as pl
+    lax = jax.lax
+
+    dim = len(terms)
+    off = dim * dim
+    used = sorted({m for row in terms for m in row})
+    extents = [a[1] for a in axes if a[0] == "c"] + [axes[-1][1] // sub]
+    steps = int(np.prod(extents))
+    tile = (sub, 128)
+
+    def body(i, carry):
+        pos = []
+        for e in reversed(extents):
+            pos.append(i % e)
+            i = i // e
+        pos.reverse()
+        rows = pl.ds(pl.multiple_of(pos[-1] * sub, sub), sub)
+
+        def index(m):
+            lead = iter(pos[:-1])
+            idx = []
+            for kind, v in axes:
+                if kind == "t":
+                    idx.append((m >> v) & 1)
+                elif kind == "c":
+                    idx.append(next(lead))
+                else:
+                    idx.append(rows)
+            return tuple(idx) + (slice(None),)
+
+        def coef(j):
+            return lax.broadcast_in_dim(u_ref[j], tile, ())
+
+        xr = {m: re_ref[index(m)] for m in used}
+        xi = {m: im_ref[index(m)] for m in used}
+        for r, row in enumerate(terms):
+            acc_r = acc_i = None
+            for m in row:
+                a = coef(r * dim + m)
+                b = coef(off + r * dim + m)
+                tr = lax.sub(lax.mul(a, xr[m]), lax.mul(b, xi[m]))
+                ti = lax.add(lax.mul(a, xi[m]), lax.mul(b, xr[m]))
+                acc_r = tr if acc_r is None else lax.add(acc_r, tr)
+                acc_i = ti if acc_i is None else lax.add(acc_i, ti)
+            if acc_r is None:
+                acc_r = acc_i = jnp.zeros(tile, ore_ref.dtype)
+            ore_ref[index(r)] = acc_r
+            oim_ref[index(r)] = acc_i
+        return carry
+
+    jax.lax.fori_loop(0, steps, body, 0)
+
+
+def apply_rowgate_planes(re: jnp.ndarray, im: jnp.ndarray, num_qubits: int,
+                         u, targets: Sequence[int],
+                         interpret: bool = False):
+    """Apply a dense uncontrolled ``2^k x 2^k`` operator (``k <= 4``,
+    every target at qubit 10 or above; bit ``j`` of ``u``'s index
+    addresses ``targets[j]``) to the ``(rows, 128)`` re/im planes of a
+    state in one HBM pass, in place. Returns the new plane pair.
+
+    The planes are viewed split at the target row bits; one grid step
+    reads the ``2^k`` partner sub-blocks of about
+    :data:`DEFAULT_BLOCK_ROWS` rows in all and writes their mix back over
+    them. ``u`` is an operand in scalar memory, so one kernel serves
+    every operator of the same target geometry, a traced one (a
+    parameterised gate) included; a host matrix's zero entries are left
+    out of the sums."""
+    targets = tuple(int(t) for t in targets)
+    if not rowgate_eligible("u", 0, targets):
+        raise ValueError(f"row-gate targets {targets} need 1 to "
+                         f"{ROWGATE_MAX_TARGETS} qubits, all >= "
+                         f"{ROWGATE_MIN_QUBIT}")
+    dim = 1 << len(targets)
+    if isinstance(u, np.ndarray):
+        terms = tuple(tuple(int(m) for m in np.flatnonzero(u[r]))
+                      for r in range(dim))
+        uop = jnp.asarray(np.concatenate([u.real.ravel(), u.imag.ravel()]),
+                          re.dtype)
+    else:
+        terms = (tuple(range(dim)),) * dim
+        uj = jnp.asarray(u)
+        uop = jnp.concatenate([jnp.real(uj).ravel(),
+                               jnp.imag(uj).ravel()]).astype(re.dtype)
+    return _rowgate_call(uop, re, im, num_qubits=int(num_qubits),
+                         targets=targets, terms=terms,
+                         interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("num_qubits", "targets",
+                                             "terms", "interpret"))
+def _rowgate_call(uop, re, im, *, num_qubits, targets, terms, interpret):
+    """The row-gate ``pallas_call`` of :func:`apply_rowgate_planes`. Jitted
+    on the geometry, so the items of one circuit that share it share one
+    trace and one lowering of the kernel."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    k = len(targets)
+    total_rows = re.shape[0]
+    shape, block, axes = _rowgate_layout(
+        num_qubits, targets, min(DEFAULT_BLOCK_ROWS, total_rows))
+    rblock = [a[1] for a in axes if a[0] == "r"][0]
+    kernel = functools.partial(_rowgate_kernel, axes=axes,
+                               sub=min(rblock, max(8, 128 >> k)),
+                               terms=terms)
+    grid_axes = [a for a in range(len(shape)) if shape[a] > block[a]]
+    grid = tuple(shape[a] // block[a] for a in grid_axes) or (1,)
+
+    def index_map(*g):
+        pos = dict(zip(grid_axes, g))
+        return tuple(pos.get(a, 0) for a in range(len(shape))) + (0,)
+
+    state_spec = pl.BlockSpec(
+        tuple(pl.squeezed if b == 1 else b for b in block) + (128,),
+        index_map)
+    view = shape + (128,)
+    out_re, out_im = pl.pallas_call(
+        kernel,
+        name=f"pallas_rowgate_{k}q",
+        grid=grid,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), state_spec,
+                  state_spec],
+        out_specs=[state_spec, state_spec],
+        out_shape=[jax.ShapeDtypeStruct(view, re.dtype)] * 2,
+        input_output_aliases={1: 0, 2: 1},
+        interpret=interpret,
+    )(uop, re.reshape(view), im.reshape(view))
+    return (out_re.reshape(total_rows, 128),
+            out_im.reshape(total_rows, 128))
 
 
 def apply_layer_batched(states: jnp.ndarray, num_qubits: int, layer: LayerOp,

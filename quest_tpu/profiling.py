@@ -659,6 +659,9 @@ class DispatchStats:
     # executable per key forever):
     batched_cache_size: int = 0        # live entries in the bounded cache
     batched_cache_evictions: int = 0   # executables dropped by the bound
+    # single-chip Pallas routing: plan items run as row-gate passes
+    # (ops/pallas_kernels.apply_rowgate_planes; 0 where Pallas is off)
+    rowgate_passes: int = 0
     # precision-tier accounting (config.PrecisionTier; "env" = the
     # legacy per-environment precision, no tier selected):
     precision_tier: str = "env"        # compile-time tier of this program
@@ -703,6 +706,7 @@ class DispatchStats:
                 "evolve_steps_fused": self.evolve_steps_fused,
                 "batched_cache_size": self.batched_cache_size,
                 "batched_cache_evictions": self.batched_cache_evictions,
+                "rowgate_passes": self.rowgate_passes,
                 "precision_tier": self.precision_tier,
                 "modeled_tier_error": self.modeled_tier_error}
 

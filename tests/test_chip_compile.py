@@ -187,6 +187,28 @@ def test_apply_mxu_tile_compiles(topo, one_chip):
     _fits(compiled)
 
 
+@pytest.mark.parametrize("targets", [(26, 27), (17, 18, 19),
+                                     (10, 11, 17, 18), (20, 27)])
+def test_apply_rowgate_planes_compiles(topo, one_chip, targets):
+    """The row gate at 28 qubits on target sets of the benchmark's random
+    circuit: contiguous, split, and the lowest target qubit 10. It
+    writes the planes in place (the input aliases the output)."""
+    from quest_tpu.ops import pallas_kernels as pk
+    k = len(targets)
+    u = np.linalg.qr(np.arange(4 ** k).reshape(1 << k, 1 << k)
+                     + 1j * np.eye(1 << k))[0]
+    spec = jax.ShapeDtypeStruct((1 << 21, 128), jnp.float32,
+                                sharding=one_chip)
+    compiled = jax.jit(lambda re, im: pk.apply_rowgate_planes(
+        re, im, 28, u, targets), donate_argnums=(0, 1)).lower(
+        spec, spec).compile()
+    assert f"%pallas_rowgate_{k}q" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 2 * 4 * (1 << 28)
+    assert m.temp_size_in_bytes < 1 << 20
+    _fits(compiled)
+
+
 def test_fused_kraus_apply_batched_compiles(topo, one_chip):
     from quest_tpu.ops import pallas_kernels as pk
     p = 0.1
