@@ -2430,6 +2430,7 @@ class CompiledCircuit:
                 inter_planned = 0.0
                 inter_saved = 0.0
                 inter_launches = 0
+                launches = 0
                 if self.plan.shard_bits:
                     from .parallel.layout import plan_comm_stats
                     from .profiling import DEFAULT_COMM_MODEL
@@ -2441,6 +2442,9 @@ class CompiledCircuit:
                     planned = tot["bytes"]
                     inter_planned = tot["inter_bytes"]
                     inter_launches = tot["inter_launches"]
+                    # an overlapped relayout issues one all_to_all per
+                    # slab, two where the plan counts one
+                    launches = tot["launches"] + self._overlapped_pairs
                     if self._baseline_pipeline is not None:
                         _, base_plan, _ = self._baseline_pipeline(False)
                         base = plan_comm_stats(base_plan,
@@ -2466,6 +2470,7 @@ class CompiledCircuit:
                 self._comm_inter_planned = inter_planned
                 self._comm_inter_saved = inter_saved
                 self._inter_launches = inter_launches
+                self._comm_launches = launches
             bs = dict(self._batch_stats or {})
             cache_evictions = self._batched_cache.evictions
             cache_size = len(self._batched_cache)
@@ -2478,6 +2483,7 @@ class CompiledCircuit:
             commuted_diagonals=fs.commuted_diagonals if fs else 0,
             max_group_gates=fs.max_group_gates if fs else 0,
             cross_shard_exchanges=self.plan.num_xshard,
+            collective_launches=self._comm_launches,
             swaps_absorbed=self.plan.swaps_absorbed,
             collectives_fused=self.plan.collectives_fused,
             comm_bytes_planned=self._comm_bytes_planned,

@@ -455,6 +455,17 @@ def _relayout_sigma(perm_before, perm_after, n: int) -> np.ndarray:
     return sigma
 
 
+def _permute_moving_share(sigma: np.ndarray, local_top: int) -> float:
+    """Share of the devices whose chunk the residual ``ppermute`` of
+    :func:`~quest_tpu.parallel.exchange.plan_exchange` moves for
+    ``sigma``: a device whose index the device-bit permutation fixes
+    keeps its chunk and sends nothing."""
+    from .exchange import plan_exchange
+    n = len(sigma)
+    pairs = plan_exchange(n, n - local_top, range(n), sigma).device_perm
+    return sum(v != w for v, w in pairs) / len(pairs)
+
+
 def relayout_comm_tiered(sigma: np.ndarray, local_top: int,
                          chunk_bytes: float, cost_model,
                          host_bits: int = 0) -> dict:
@@ -471,7 +482,9 @@ def relayout_comm_tiered(sigma: np.ndarray, local_top: int,
     exchanged device slot is inter-host; the residual ``ppermute``
     (conservatively) iff ANY inter-host slot participates in the
     relayout at all. Returns ``{"seconds", "bytes", "inter_bytes",
-    "launches", "inter_launches"}`` (per-device bytes)."""
+    "launches", "inter_launches"}``: bytes are a device's mean, so the
+    residual ``ppermute`` charges its chunk to the devices it moves only,
+    while its seconds are those of a whole chunk on the wire."""
     n = len(sigma)
     lt = local_top
     inter_lo = n - max(0, min(host_bits, n - lt))
@@ -498,7 +511,8 @@ def relayout_comm_tiered(sigma: np.ndarray, local_top: int,
     if residual:
         seconds += cost_model.ppermute_seconds(chunk_bytes,
                                                inter=res_inter)
-        b = cost_model.ppermute_bytes(chunk_bytes)
+        b = cost_model.ppermute_bytes(chunk_bytes) \
+            * _permute_moving_share(sigma, lt)
         nbytes += b
         launches += 1
         if res_inter:

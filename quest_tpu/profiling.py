@@ -635,6 +635,10 @@ class DispatchStats:
     max_group_gates: int = 0     # largest gates-per-group count
     # communication-planner accounting (quest_tpu/parallel/layout.py):
     cross_shard_exchanges: int = 0  # 1q pair-exchange items in the plan
+    # collective ops the program issues per run (each relayout's
+    # all_to_all and residual ppermute, each pair exchange) — the
+    # communication planner's primary observable
+    collective_launches: int = 0
     swaps_absorbed: int = 0      # SWAP gates composed into the layout perm
     collectives_fused: int = 0   # relayout pairs merged into one exchange
     comm_bytes_planned: float = 0.0  # mesh-total collective bytes per run
@@ -673,13 +677,6 @@ class DispatchStats:
         relayout and pair exchanges) — the number the fusion pass and the
         communication planner exist to shrink."""
         return self.kernels_out + self.relayouts + self.cross_shard_exchanges
-
-    @property
-    def collective_launches(self) -> int:
-        """Collectives issued per program execution (relayout exchanges
-        plus cross-shard pair exchanges) — the communication planner's
-        primary observable."""
-        return self.relayouts + self.cross_shard_exchanges
 
     def as_dict(self) -> dict:
         return {"gates_in": self.gates_in,

@@ -283,3 +283,57 @@ def test_sharded_program_fits_v5e_2x2(topo, f32_env, monkeypatch):
     assert need >= 2 * 4 * (1 << n) // 4        # the 2 GiB shard is there
     text = compiled.as_text()
     assert any(op in text for op in ("all-to-all", "collective-permute"))
+
+
+def test_sharded_program_names_its_layers(topo, f32_env, monkeypatch):
+    """Over a described 2x2 mesh the ``shard_map`` body's Pallas layers
+    keep their kernel names (``pallas_layer_<k>gates``): a four-chip
+    cell's trace shows its layers under the names a single chip's does."""
+    import quest_tpu as qt
+    from jax.sharding import Mesh
+    from quest_tpu.env import AMP_AXIS
+    cs = _smoke()
+    n = 22
+    mesh = Mesh(np.asarray(topo.devices), (AMP_AXIS,))
+    env = qt.QuESTEnv(precision=qt.SINGLE, mesh=mesh, key=f32_env.key)
+    keep = {0, 1, 2, 8, 9, n - 2, n - 1}
+    spec = [g for g in cs.unitary_spec(n, layers=1)
+            if (g[0] == "rot" and g[1] in keep)
+            or (g[0] == "cnot" and g[1] in (0, 8, n - 2))]
+    cc = _compile_for_tpu(monkeypatch, cs.build_circuit(qt, n, spec), env)
+    assert cs.pallas_layers(cc) > 0
+    assert cc.dispatch_stats().relayouts > 0
+    state = jax.ShapeDtypeStruct(
+        (2, 1 << n), jnp.float32,
+        sharding=NamedSharding(mesh, PartitionSpec(None, AMP_AXIS)))
+    vec = jax.ShapeDtypeStruct((0,), jnp.float32,
+                               sharding=NamedSharding(mesh, PartitionSpec()))
+    text = cc._jitted.lower(state, vec).compile().as_text()
+    assert "%pallas_layer_" in text
+    assert "all-to-all" in text
+
+
+def test_sharded_reference_fits_v5e_2x2(topo):
+    """The plain reference of the benchmark's four-chip cell
+    (``benchmark/reference/rcs_mesh.py``) at its 30 qubits over a
+    described 2x2 mesh: a chip holds its 2 GiB share and one step's
+    temporaries, and the state moves between chips only by all-to-all,
+    never gathered whole."""
+    import json
+    from jax.sharding import Mesh
+    from benchmark.reference import rcs_mesh
+    with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                           "benchmark", "configs",
+                           "sycamore-rcs-30.json")) as f:
+        cfg = json.load(f)
+    n = cfg["qubits"]
+    mesh = Mesh(np.asarray(topo.devices), ("amps",))
+    planes = jax.ShapeDtypeStruct(
+        (2, 1 << n), jnp.float32,
+        sharding=NamedSharding(mesh, PartitionSpec(None, "amps")))
+    compiled = rcs_mesh.make_apply(cfg, "highest").lower(planes).compile()
+    need = _fits(compiled)
+    assert need < 6 * 2 * 4 * (1 << n) // 4     # a few 2 GiB buffers
+    text = compiled.as_text()
+    assert "all-to-all" in text
+    assert "all-gather" not in text

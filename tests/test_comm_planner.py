@@ -42,7 +42,7 @@ class TestCostModelOracle:
         (``relayout_comm``) must agree with the actual choreography
         ``plan_exchange`` produces — all_to_all bytes from the exchanged
         bit count, ppermute bytes iff a residual device permutation
-        remains."""
+        remains, charged to the devices whose chunk it moves."""
         n, s = 5, 2
         lt = n - s
         B = 16.0 * (1 << lt)
@@ -56,7 +56,8 @@ class TestCostModelOracle:
                 oracle_bytes += B * ((1 << plan.k) - 1) / (1 << plan.k)
                 oracle_launches += 1
             if plan.device_perm is not None:
-                oracle_bytes += B
+                moved = sum(v != w for v, w in plan.device_perm)
+                oracle_bytes += B * moved / len(plan.device_perm)
                 oracle_launches += 1
             sigma = _relayout_sigma(before, after, n)
             sec, got_bytes, got_launches = relayout_comm(sigma, lt, B,
